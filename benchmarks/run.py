@@ -111,6 +111,8 @@ def main() -> None:
             ap.error("--report only reads benchmarks/results/; drop "
                      "--smoke/--only")
         sys.exit(trajectory_report())
+    from repro.compile_cache import setup_compile_cache
+    setup_compile_cache()
     which = set((args.only or
                  "figures,table2,kernels,roofline,ablations,protocol,"
                  "staleness,faults,wire,serving,obs,analysis")

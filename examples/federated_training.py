@@ -15,6 +15,7 @@ the seed-vmapped sweep cell (one compile per mode) for ``--seeds k``
 import argparse
 
 from repro.api import ExperimentSpec, build
+from repro.compile_cache import setup_compile_cache
 
 
 def report(name, rr):
@@ -32,6 +33,7 @@ def report(name, rr):
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--clients", type=int, default=5)
     ap.add_argument("--dataset", default="mnist",

@@ -11,7 +11,9 @@ seconds on CPU (it is the CI examples-smoke lane); for the LM
 substrate demo see examples/quickstart_lm.py.
 """
 from repro.api import ExperimentSpec, build
+from repro.compile_cache import setup_compile_cache
 
+setup_compile_cache()
 spec = ExperimentSpec(dataset="titanic", mode="devertifl", n_clients=3,
                       rounds=3, epochs=2, seeds=(0,))
 result = build(spec).run()

@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.reduced import reduced_config
+from repro.compile_cache import setup_compile_cache
 from repro.data import markov_lm_batches
 from repro.launch.train import make_train_step
 from repro.models import build_model
@@ -16,6 +17,7 @@ from repro.optim import adam
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
     ap.add_argument("--steps", type=int, default=20)

@@ -18,9 +18,11 @@ import numpy as np
 
 from repro.api import ExperimentSpec, ServeRequest, build, \
     split_features
+from repro.compile_cache import setup_compile_cache
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="toy sizes (the scripts/ci.sh examples lane)")

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint import load_checkpoint, latest_step, save_checkpoint
+from repro.compile_cache import setup_compile_cache
 from repro.configs import get_config
 from repro.data import markov_lm_batches
 from repro.launch.train import make_train_step
@@ -35,6 +36,7 @@ PRESETS = {
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="20m", choices=list(PRESETS))
     ap.add_argument("--steps", type=int, default=60)

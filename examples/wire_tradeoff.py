@@ -9,11 +9,13 @@ Smoke: PYTHONPATH=src python examples/wire_tradeoff.py --smoke
 import argparse
 
 from repro.api import run_grid, spec_grid
+from repro.compile_cache import setup_compile_cache
 
 TRANSFORMS = ("none", "int8", "topk:0.5+int8+dp:0.1")
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="CI sizes (1 round, 1 seed)")

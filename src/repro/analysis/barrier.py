@@ -37,12 +37,12 @@ from __future__ import annotations
 import threading
 from contextlib import contextmanager
 
-from jax import core as jcore
+from jax.extend.core import Primitive
 from jax.interpreters import ad, batching, mlir
 
 TAG_PRIM_NAME = "repro_audit_tag"
 
-tag_p = jcore.Primitive(TAG_PRIM_NAME)
+tag_p = Primitive(TAG_PRIM_NAME)
 tag_p.def_impl(lambda x, **_: x)
 tag_p.def_abstract_eval(lambda aval, **_: aval)
 # linear: jvp passes tangents through, and the transpose of a DECLARED

@@ -149,6 +149,10 @@ class DeadnessInterpreter(ir.AbstractInterpreter):
         if name == "concatenate":
             return [np.concatenate(in_abs,
                                    axis=eqn.params["dimension"])]
+        if name == "split":
+            cuts = np.cumsum(eqn.params["sizes"])[:-1]
+            return [p.copy() for p in
+                    np.split(in_abs[0], cuts, axis=eqn.params["axis"])]
         if name == "pad":
             return [self._pad(in_abs, eqn, out_shape)]
         if name == "dynamic_slice":

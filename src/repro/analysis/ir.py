@@ -30,8 +30,8 @@ import re
 
 import numpy as np
 
-import jax
-from jax import core as jcore
+from jax.extend import core as jcore
+from jax.extend.source_info_util import summarize
 
 # ----------------------------------------------------------------------
 # HLO text helpers (single source of truth for the roofline parsers)
@@ -81,7 +81,7 @@ def bytes_of(type_str: str) -> int:
 # equation is transparent: map invars -> sub-jaxpr args, run, map back).
 # scan / while / cond have their own drivers; anything else (notably
 # pallas_call) falls to the conservative default rule, which is sound.
-INLINE_CALLS = ("pjit", "closed_call", "core_call", "custom_jvp_call",
+INLINE_CALLS = ("jit", "closed_call", "core_call", "custom_jvp_call",
                 "custom_vjp_call", "custom_vjp_call_jaxpr", "remat",
                 "remat2", "checkpoint")
 
@@ -95,7 +95,7 @@ def closed(j):
 
 def sub_jaxprs(eqn):
     """Yield every (ClosedJaxpr) nested in an equation's params --
-    pjit/scan ``jaxpr``, cond ``branches``, while ``cond_jaxpr`` /
+    jit/scan ``jaxpr``, cond ``branches``, while ``cond_jaxpr`` /
     ``body_jaxpr``, custom_jvp ``call_jaxpr`` -- uniformly closed."""
     for val in eqn.params.values():
         vals = val if isinstance(val, (tuple, list)) else (val,)
@@ -121,7 +121,7 @@ def all_eqns(jaxpr):
 
 def inline_jaxpr_of(eqn):
     """The single inlinable sub-jaxpr of a transparent call equation
-    (pjit's ``jaxpr``, custom_jvp's ``call_jaxpr``), or None."""
+    (jit's ``jaxpr``, custom_jvp's ``call_jaxpr``), or None."""
     for key in ("jaxpr", "call_jaxpr", "fun_jaxpr"):
         v = eqn.params.get(key)
         if isinstance(v, (jcore.ClosedJaxpr, jcore.Jaxpr)):
@@ -133,14 +133,9 @@ def eqn_line(eqn, path=""):
     """One-line human rendering of an equation for reports: primitive,
     output avals, and the source location jax recorded at trace time."""
     outs = ", ".join(str(v.aval) for v in eqn.outvars)
-    src = ""
-    try:
-        frame = jax._src.source_info_util.user_frame(eqn.source_info)
-        if frame is not None:
-            src = f"  [{frame.file_name.rsplit('/', 1)[-1]}:"\
-                  f"{frame.start_line}]"
-    except Exception:
-        pass
+    # "path/file.py:LINE:COL (fn)" of the user frame -> "[file.py:LINE]"
+    where_src = summarize(eqn.source_info).split(" ", 1)[0]
+    src = f"  [{':'.join(where_src.rsplit('/', 1)[-1].split(':')[:2])}]"
     where = f"{path}/" if path else ""
     return f"{where}{eqn.primitive.name} -> {outs}{src}"
 
@@ -250,8 +245,8 @@ class AbstractInterpreter:
         return self.conc_env.get(var)
 
     def write(self, var, abs_val, conc_val=None, eqn=None):
-        if isinstance(var, jcore.DropVar):
-            return
+        # dropped outputs (``_`` in the jaxpr) are written like any var:
+        # no equation reads them, so their entries are never looked up
         self.abs_env[var] = abs_val
         if conc_val is not None:
             self.conc_env[var] = conc_val
@@ -332,7 +327,7 @@ class AbstractInterpreter:
     def _inline(self, eqn, in_abs, in_conc):
         sub = inline_jaxpr_of(eqn)
         n = len(sub.jaxpr.invars)
-        # custom_jvp_call passes (primal args); pjit passes all invars
+        # custom_jvp_call passes (primal args); jit passes all invars
         return self._nested(sub, eqn, in_abs[:n], in_conc[:n])[:len(
             eqn.outvars)]
 

@@ -41,7 +41,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.analysis import ir
 from repro.analysis.barrier import TAG_PRIM_NAME
@@ -266,6 +266,8 @@ class TaintInterpreter(ir.AbstractInterpreter):
             return [self._pad(in_abs, eqn)]
         if name == "concatenate":
             return [self._concatenate(in_abs, eqn)]
+        if name == "split":
+            return self._split(in_abs[0], eqn)
         if name == "dot_general":
             return [self._dot_general(in_abs, eqn)]
         if name == "gather":
@@ -407,6 +409,14 @@ class TaintInterpreter(ir.AbstractInterpreter):
         strides = eqn.params.get("strides")
         step = strides[k] if strides else 1
         return perslot(k, a.bits[start:limit:step].copy())
+
+    def _split(self, a, eqn):
+        sizes = eqn.params["sizes"]
+        if a.axis is None or a.axis != eqn.params["axis"]:
+            return [a] * len(sizes)
+        starts = np.concatenate([[0], np.cumsum(sizes)[:-1]])
+        return [perslot(a.axis, a.bits[s:s + n].copy())
+                for s, n in zip(starts, sizes)]
 
     def _dynamic_slice(self, in_abs, in_conc, eqn):
         a = in_abs[0]

@@ -23,10 +23,8 @@ order.
 
 On the canonical layout the zero-padding masks become contiguous slabs,
 the XLA engine path can ``dynamic_slice`` instead of masking, and the
-``vfl_matmul`` Pallas kernel can walk only the client's weight-row
-blocks.  ``Layout.block`` is the largest block size (capped at 128)
-that divides every slice size -- and therefore every offset -- so all
-slices are block-aligned for the kernel's BlockSpec index_map.
+``vfl_matmul`` Pallas kernel can multiply only the client's weight
+rows.
 
 Padded client axes
 ------------------
@@ -45,7 +43,6 @@ See docs/ARCHITECTURE.md for the full Layout/LayoutArrays contract.
 """
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass
 from typing import NamedTuple, Sequence, Tuple
 
@@ -138,10 +135,8 @@ class Layout:
     inv_perm    [F] original feature f lives at canonical column
                 inv_perm[f]
     offsets     per-client canonical slice starts (python ints: static
-                under jit, usable in Pallas BlockSpec index_maps)
+                under jit, the ``vfl_matmul`` kernel's W-row offsets)
     sizes       per-client slice lengths F_i (0 for dead padding slots)
-    block       largest bk <= 128 dividing every live size (hence
-                every offset)
     n_real      number of LIVE participants; clients [n_real,
                 n_clients) are dead padding slots added by ``pad``
     """
@@ -150,7 +145,6 @@ class Layout:
     inv_perm: np.ndarray
     offsets: Tuple[int, ...]
     sizes: Tuple[int, ...]
-    block: int
     n_features: int
     n_real: int
 
@@ -202,15 +196,6 @@ class Layout:
                             client_mask=jnp.asarray(self.client_mask()))
 
 
-def _block_of(sizes: Sequence[int], cap: int = 128) -> int:
-    g = 0
-    for s in sizes:
-        g = math.gcd(g, int(s))
-    if g == 0:
-        return 1
-    return max(d for d in range(1, min(g, cap) + 1) if g % d == 0)
-
-
 def canonicalize(partition, n_features: int) -> Layout:
     """Build the canonical contiguous layout for a partition: column j
     of the canonical order is original feature ``perm[j]``, client i's
@@ -227,8 +212,7 @@ def canonicalize(partition, n_features: int) -> Layout:
     offsets = tuple(int(o) for o in
                     np.concatenate([[0], np.cumsum(sizes)[:-1]]))
     return Layout(partition=parts, perm=perm, inv_perm=inv_perm,
-                  offsets=offsets, sizes=sizes,
-                  block=_block_of(sizes), n_features=n_features,
+                  offsets=offsets, sizes=sizes, n_features=n_features,
                   n_real=len(parts))
 
 

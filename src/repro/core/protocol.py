@@ -39,7 +39,7 @@ selects how layer 0 is computed:
            into the client's W-row block; rows outside the slice get
            the same exact-zero gradient the masked path produces.
   pallas   the block-sparse ``vfl_matmul`` Pallas kernel (with its
-           custom VJP) walking only the client's weight-row blocks --
+           custom VJP) multiplying only the client's weight rows --
            the TPU path; on CPU it runs in interpret mode.
   auto     pallas on TPU, slice elsewhere (the default).
 
@@ -351,13 +351,16 @@ def _ce(logits, labels):
 
 
 def _masked_mean(values, client_mask):
-    """Mean over live clients: sum(v * mask) * (1/n_live).  The
-    reciprocal MULTIPLY (not a divide) matters: XLA lowers ``mean`` to
-    sum * (1/n), so this is bit-for-bit ``values[:n_live].mean()`` when
-    the dead tail is masked to exact zeros -- a traced divide would
-    differ in the last ulp."""
+    """Mean over live clients: sum(v * mask) * (1/n_live).  The sum is
+    a left fold over the static client axis, so a dead tail of exact
+    zeros adds nothing and a padded federation reports its unpadded
+    run's loss bit for bit (a ``reduce_sum`` may pair its terms
+    differently at different lengths)."""
     term = tag(values * client_mask, "term", "loss", client_axis=0)
-    return term.sum() * (1.0 / client_mask.sum())
+    total = term[0]
+    for i in range(1, term.shape[0]):
+        total = total + term[i]
+    return total * (1.0 / client_mask.sum())
 
 
 def _masked_hidden_sum(h_all, client_mask):
@@ -372,12 +375,12 @@ def make_first_layer_fn(model, pcfg, layout, interpret=None):
     """first(params, xb, lay) -> [n_clients, B, H] post-ReLU layer-0
     activations.  xb is the canonical-order [B, F] batch; lay is the
     LayoutArrays view (lay.offsets is traced -- sweeps vmap it); the
-    static slice sizes (and, for pallas, static offsets and block size)
-    come from ``layout``.
+    static slice sizes (and, for pallas, static offsets) come from
+    ``layout``.
 
-    CAVEAT (pallas): the Pallas BlockSpec index_map needs *static*
-    offsets, so first_pallas closes over ``layout.offsets`` and
-    ignores the runtime ``lay.offsets``.  Callers must pass
+    CAVEAT (pallas): the kernel slices W with *static* offsets, so
+    first_pallas closes over ``layout.offsets`` and ignores the
+    runtime ``lay.offsets``.  Callers must pass
     LayoutArrays derived from the same canonical Layout (canonical
     offsets are deterministic per (dataset, n_clients), and
     sweep._stacked_federations raises if lanes ever disagreed); a
@@ -419,12 +422,11 @@ def make_first_layer_fn(model, pcfg, layout, interpret=None):
             return jnp.stack(outs)
         return first_slice
 
-    # pallas: BlockSpec index_maps need static offsets; the canonical
-    # layout's offsets are deterministic per (dataset, n_clients), so
-    # closing over them is safe even in seed-vmapped sweeps.
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    offsets, bk = layout.offsets, layout.block
+    # pallas: the kernel needs static offsets; the canonical layout's
+    # offsets are deterministic per (dataset, n_clients), so closing
+    # over them is safe even in seed-vmapped sweeps.  interpret=None
+    # compiles the kernel on a TPU and interprets it elsewhere.
+    offsets = layout.offsets
 
     def first_pallas(params, xb, lay):
         w = params["layer_0"]["kernel"]
@@ -440,7 +442,7 @@ def make_first_layer_fn(model, pcfg, layout, interpret=None):
                 outs.append(dead_h1(xb, b[i], w.shape[-1]))
                 continue
             x_i = jax.lax.slice_in_dim(xb, off, off + f_i, axis=1)
-            y = vfl_matmul(x_i, w[i], off, bk=bk, interpret=interpret)
+            y = vfl_matmul(x_i, w[i], off, interpret=interpret)
             outs.append(jax.nn.relu(y + b[i]))
         return jnp.stack(outs)
     return first_pallas

@@ -31,7 +31,7 @@ is allclose instead, because its contraction length is padded.
 Device scale-out
 ----------------
 Lanes have no cross-lane dataflow, so ``run_padded_cells`` distributes
-them over the device mesh with ``repro.compat.shard_map`` under the
+them over the device mesh with ``jax.shard_map`` under the
 ``repro.sharding`` rules ("sweep_lane" -> the data-parallel mesh
 axes).  The lane axis is split over the largest device count that
 divides it; on a single device the shard_map is skipped.  Sharded and
@@ -73,7 +73,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import sharding as sh
-from repro.compat import shard_map
 from repro.configs import get_config
 from repro.core import partition as PT
 from repro.core.protocol import (FIRST_LAYERS, ProtocolConfig, arch_for,
@@ -849,11 +848,12 @@ def run_padded_cells(dataset, mode, scfg, shard="auto"):
     vround = jax.vmap(counted_round)
     n_dev = _lane_shards(n_lanes, shard)
     if n_dev > 1:
-        mesh = jax.make_mesh((n_dev,), ("data",))
+        mesh = jax.make_mesh((n_dev,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         with sh.use_context(mesh):
             spec = sh.logical_spec("sweep_lane")    # -> P("data")
-        vround = shard_map(vround, mesh=mesh, in_specs=(spec,) * 8,
-                           out_specs=spec, check_vma=False)
+        vround = jax.shard_map(vround, mesh=mesh, in_specs=(spec,) * 8,
+                               out_specs=spec, check_vma=False)
     vround = jax.jit(vround, donate_argnums=(0, 1))
     vpred = jax.jit(jax.vmap(
         make_predict_fn(lb.model, pcfg, first_layer_fn=lb.first)))
@@ -903,6 +903,8 @@ def run_padded_cells(dataset, mode, scfg, shard="auto"):
                             "acc_mean": float(np.mean(accs[sl])),
                             "final_loss_mean":
                                 float(losses_np[sl, -1].mean()),
+                            "final_loss_per_seed":
+                                losses_np[sl, -1].tolist(),
                             # the whole multi-count batch trains
                             # together, so wall_s is SHARED across
                             # this group's cells and each cell's

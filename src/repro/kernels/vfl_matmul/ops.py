@@ -29,21 +29,21 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_default
 from repro.kernels.vfl_matmul.vfl_matmul import vfl_matmul_p
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
-def _vfl_matmul(x_local, w_full, offset, bm, bn, bk, interpret):
-    return vfl_matmul_p(x_local, w_full, offset, bm=bm, bn=bn, bk=bk,
-                        interpret=interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _vfl_matmul(x_local, w_full, offset, bm, interpret):
+    return vfl_matmul_p(x_local, w_full, offset, bm=bm, interpret=interpret)
 
 
-def _vfl_matmul_fwd(x_local, w_full, offset, bm, bn, bk, interpret):
-    y = _vfl_matmul(x_local, w_full, offset, bm, bn, bk, interpret)
+def _vfl_matmul_fwd(x_local, w_full, offset, bm, interpret):
+    y = _vfl_matmul(x_local, w_full, offset, bm, interpret)
     return y, (x_local, w_full)
 
 
-def _vfl_matmul_bwd(offset, bm, bn, bk, interpret, res, g):
+def _vfl_matmul_bwd(offset, bm, interpret, res, g):
     x_local, w_full = res
     k_local = x_local.shape[1]
     w_slice = jax.lax.slice_in_dim(w_full, offset, offset + k_local,
@@ -60,15 +60,14 @@ def _vfl_matmul_bwd(offset, bm, bn, bk, interpret, res, g):
 _vfl_matmul.defvjp(_vfl_matmul_fwd, _vfl_matmul_bwd)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("offset", "bm", "bn", "bk", "interpret"))
-def vfl_matmul(x_local, w_full, offset: int, *, gate=None, bm=128, bn=128,
-               bk=128, interpret=True):
+@functools.partial(jax.jit, static_argnames=("offset", "bm", "interpret"))
+def vfl_matmul(x_local, w_full, offset: int, *, gate=None, bm=128,
+               interpret=None):
     """y = zeropad(x_local) @ w_full without materializing the padding.
 
-    Differentiable (custom VJP above). interpret defaults to True
-    because this container is CPU-only; on TPU pass interpret=False to
-    run the compiled kernel.
+    Differentiable (custom VJP above). interpret=None runs the compiled
+    kernel on a TPU and the Pallas interpreter elsewhere
+    (``repro.kernels.interpret_default``).
 
     gate: optional traced scalar (e.g. a LayoutArrays.client_mask
     entry) multiplied into the output; gate=0.0 zeroes y AND both
@@ -76,7 +75,9 @@ def vfl_matmul(x_local, w_full, offset: int, *, gate=None, bm=128, bn=128,
     a bitwise no-op.  This is how padded federations mask dead client
     lanes through the kernel path.
     """
-    y = _vfl_matmul(x_local, w_full, offset, bm, bn, bk, interpret)
+    if interpret is None:
+        interpret = interpret_default()
+    y = _vfl_matmul(x_local, w_full, offset, bm, interpret)
     if gate is not None:
         y = y * jnp.asarray(gate, y.dtype)
     return y

@@ -5,14 +5,19 @@ The paper's client multiplies a zero-padded full-width input x' by the
 first-layer weight W: y = zeropad(x_local) @ W. All rows of W outside
 the client's feature slice meet zeros; a dense matmul wastes
 (n_clients-1)/n_clients of the MXU work. This kernel computes
-y = x_local @ W[offset:offset+F_local] by *indexing* the weight blocks
-through the BlockSpec index_map -- the padding is never materialized
-and no zero-block is ever loaded into VMEM.
+y = x_local @ W[offset:offset+K_local] instead: the padded input is
+never built, and the rows outside the slice are never multiplied.
 
-Grid: (M/bm, N/bn, K_local/bk); the K grid walks only the client's
-feature blocks; index_map offsets the W block row by the client's slice
-start. Accumulation in fp32 VMEM scratch, written out on the last K
-step.
+Grid: (cdiv(M, bm),) over row blocks. Each step takes a (bm, K_local)
+block of the client's input -- the whole slice width -- and the whole
+[K_full, N] weight, whose block index never changes, so it is copied
+into VMEM once. The kernel slices the client's rows out of it with a
+static ``pl.ds(offset, K_local)``. Client slices of the canonical
+layouts (mnist rows of 28 or 98 columns, bank's 17-column thirds,
+skewed splits) start and end anywhere, so the slice lives inside the
+kernel: the TPU compiler accepts an unaligned static slice of a VMEM
+ref, but not a BlockSpec block whose last two dims are neither
+multiples of (8, 128) nor the array's own.
 """
 from __future__ import annotations
 
@@ -21,53 +26,54 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+# VMEM the two double-buffered input blocks and the output block may
+# take; beyond it the layout is refused by name instead of by the
+# compiler (v5e scoped VMEM defaults to 16 MiB)
+VMEM_BUDGET = 12 * 1024 * 1024
 
 
-def _kernel(x_ref, w_ref, o_ref, acc_ref, *, n_k):
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
-                            preferred_element_type=jnp.float32)
-
-    @pl.when(k == n_k - 1)
-    def _out():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+def _tile_bytes(rows, cols, itemsize):
+    """VMEM footprint of a [rows, cols] block padded to (8, 128) tiles."""
+    return (-(-rows // 8) * 8) * (-(-cols // 128) * 128) * itemsize
 
 
-def vfl_matmul_p(x_local, w_full, offset: int, *, bm=128, bn=128, bk=128,
-                 interpret=False):
+def _kernel(x_ref, w_ref, o_ref, *, offset, k_local):
+    w = w_ref[pl.ds(offset, k_local), :]
+    o_ref[...] = jnp.dot(x_ref[...], w,
+                         preferred_element_type=jnp.float32
+                         ).astype(o_ref.dtype)
+
+
+def vfl_matmul_p(x_local, w_full, offset: int, *, bm=128, interpret=False):
     """x_local: [M, K_local] (client's features, contiguous slice);
-    w_full: [K_full, N]; offset: slice start (static, multiple of bk).
+    w_full: [K_full, N]; offset: static slice start.
     Returns zeropad(x_local) @ w_full == x_local @ w_full[offset:...]."""
     M, K_local = x_local.shape
     K_full, N = w_full.shape
+    if not 0 <= offset <= K_full - K_local:
+        raise ValueError(f"client slice [{offset}, {offset + K_local}) "
+                         f"lies outside W's {K_full} rows")
     bm = min(bm, M)
-    bn = min(bn, N)
-    bk = min(bk, K_local)
-    assert offset % bk == 0 and K_local % bk == 0, \
-        "client slice must be block-aligned"
-    assert offset + K_local <= K_full
-    n_k = K_local // bk
-    off_blocks = offset // bk
-
-    grid = (pl.cdiv(M, bm), pl.cdiv(N, bn), n_k)
-    kernel = functools.partial(_kernel, n_k=n_k)
+    item = x_local.dtype.itemsize
+    vmem = (2 * _tile_bytes(bm, K_local, item)
+            + 2 * _tile_bytes(K_full, N, w_full.dtype.itemsize)
+            + 2 * _tile_bytes(bm, N, item))
+    if vmem > VMEM_BUDGET:
+        raise ValueError(
+            f"vfl_matmul layout (M={M}, K_local={K_local}, "
+            f"offset={offset}, K_full={K_full}, N={N}, bm={bm}) needs "
+            f"{vmem} B of VMEM, over the {VMEM_BUDGET} B budget")
+    kernel = functools.partial(_kernel, offset=offset, k_local=K_local)
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(pl.cdiv(M, bm),),
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            # the block-sparse trick: W's K-block index is offset by the
-            # client's slice start -- zero blocks are never touched
-            pl.BlockSpec((bk, bn), lambda i, j, k: (off_blocks + k, j)),
+            pl.BlockSpec((bm, K_local), lambda i: (i, 0)),
+            pl.BlockSpec((K_full, N), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        out_specs=pl.BlockSpec((bm, N), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, N), x_local.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="vfl_matmul",
     )(x_local, w_full)

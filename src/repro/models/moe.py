@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models import layers as L
@@ -172,7 +171,7 @@ def _moe_expert_compute_ep(params, xg, ig, wg, cfg, E, C, mesh, axis, n):
 
     bspec = P(batch_axes, None, None)
     espec = P(axis, None, None)
-    y = shard_map(
+    y = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(bspec, bspec, bspec, espec, espec, espec),
         out_specs=bspec, check_vma=False)(

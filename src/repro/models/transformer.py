@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 
 from repro.models import attention as A
 from repro.models import layers as L
@@ -431,20 +430,20 @@ def embed_input(params, ids, cfg, prefix_emb=None):
             # table_local: [V, D/n] -- this client's vertical feature slice
             emb = jnp.take(table_local, ids_local, axis=0)  # [B_l,S,D/n]
             return exchange_features(emb, axis, n, mode, batch_axes)
-        h = shard_map(local_fn, mesh=mesh,
-                      in_specs=(P(None, axis), bspec),
-                      out_specs=out_spec, check_vma=False)(table, ids)
+        h = jax.shard_map(local_fn, mesh=mesh,
+                          in_specs=(P(None, axis), bspec),
+                          out_specs=out_spec, check_vma=False)(table, ids)
     else:
         def local_fn(table_local, ids_local, prefix_local):
             emb = jnp.take(table_local, ids_local, axis=0)
             emb = jnp.concatenate(
                 [prefix_local.astype(emb.dtype), emb], axis=1)
             return exchange_features(emb, axis, n, mode, batch_axes)
-        h = shard_map(local_fn, mesh=mesh,
-                      in_specs=(P(None, axis), bspec,
-                                P(batch_axes, None, axis)),
-                      out_specs=out_spec, check_vma=False)(
-                          table, ids, prefix_emb)
+        h = jax.shard_map(local_fn, mesh=mesh,
+                          in_specs=(P(None, axis), bspec,
+                                    P(batch_axes, None, axis)),
+                          out_specs=out_spec, check_vma=False)(
+                              table, ids, prefix_emb)
     return h * jnp.asarray(emb_scale, h.dtype)
 
 

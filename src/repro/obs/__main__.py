@@ -55,6 +55,8 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    from repro.compile_cache import setup_compile_cache
+    setup_compile_cache()
     if args.prom and not args.serve:
         args.serve = 4
 

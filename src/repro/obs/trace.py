@@ -25,8 +25,8 @@ zero-overhead-when-off invariant (docs/ARCHITECTURE.md section 12).
 
 ``profile_to(dir)`` optionally brackets a region with
 ``jax.profiler.start_trace/stop_trace`` so a device-level profile can
-be captured alongside the host spans; it degrades to a plain span when
-the profiler is unavailable on this backend.
+be captured alongside the host spans; a profiler that cannot start
+raises rather than leaving a run without the trace it asked for.
 """
 from __future__ import annotations
 
@@ -81,26 +81,19 @@ class SpanTracer:
     def profile_to(self, profile_dir: Optional[str]):
         """A span that additionally captures a ``jax.profiler`` device
         trace into ``profile_dir``.  ``None`` is a pure no-op (no span
-        either -- the caller asked for nothing); an unavailable
-        profiler degrades to the plain span."""
+        either -- the caller asked for nothing); a profiler that cannot
+        start raises."""
         if not profile_dir:
             yield
             return
-        started = False
-        try:
-            import jax
-            jax.profiler.start_trace(profile_dir)
-            started = True
-        except Exception:
-            started = False
+        import jax
+        jax.profiler.start_trace(profile_dir)
         try:
             with self.span("jax_profile", cat="profiler",
                            dir=profile_dir):
                 yield
         finally:
-            if started:
-                import jax
-                jax.profiler.stop_trace()
+            jax.profiler.stop_trace()
 
     # ------------------------------------------------------------------
     def to_records(self) -> List[dict]:

@@ -29,7 +29,7 @@ from dataclasses import dataclass, field, replace
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 @dataclass
@@ -99,8 +99,19 @@ class _Ctx:
 _ctx = _Ctx()
 
 
+def auto_axes(mesh: Optional[Mesh]) -> Optional[Mesh]:
+    """The same devices and axis names with every axis Auto.  The rules
+    are GSPMD hints (``with_sharding_constraint`` and jit shardings),
+    which refer to Auto axes only, while ``jax.make_mesh`` builds
+    Explicit axes by default."""
+    if mesh is None or all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
 def set_context(mesh: Optional[Mesh], rules: Optional[AxisRules] = None):
-    _ctx.mesh = mesh
+    _ctx.mesh = auto_axes(mesh)
     if rules is not None:
         _ctx.rules = rules
 
@@ -320,5 +331,6 @@ def batch_specs(batch_shape):
 
 
 def named_sharding_tree(specs, mesh: Mesh):
+    mesh = auto_axes(mesh)
     return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
                         is_leaf=lambda x: isinstance(x, P))

@@ -34,13 +34,15 @@ def test_vfl_matmul(M, Kl, Kf, off, dtype):
     allclose(out, ref, dtype)
 
 
-@pytest.mark.parametrize("M,Kl,Kf,off,bk", [
-    (16, 128, 512, 128, 128),    # aligned to default block
-    (8, 56, 140, 28, 28),        # mnist-style row-block alignment
-    (32, 128, 128, 0, 128),      # whole-width client
-    (6, 3, 9, 3, 3),             # titanic-sized tiny blocks
+@pytest.mark.parametrize("M,Kl,Kf,off", [
+    (16, 128, 512, 128),         # lane-aligned slice
+    (8, 56, 140, 28),            # mnist-style whole-image-row slice
+    (32, 128, 128, 0),           # whole-width client
+    (6, 3, 9, 3),                # titanic-sized tiny slice
+    (64, 17, 51, 17),            # bank's unaligned 17-column thirds
+    (200, 98, 784, 392),         # a partial last row block (bm=128)
 ])
-def test_vfl_matmul_grads_match_ref(M, Kl, Kf, off, bk):
+def test_vfl_matmul_grads_match_ref(M, Kl, Kf, off):
     """custom_vjp vs autodiff through the zeropad oracle: dx is the
     sliced g @ W.T, dW scatter-adds into the client's row block (exact
     zeros elsewhere).  interpret=True so the CPU suite exercises the
@@ -51,7 +53,7 @@ def test_vfl_matmul_grads_match_ref(M, Kl, Kf, off, bk):
     t = jax.random.normal(ks[2], (M, 32), jnp.float32)  # cotangent seed
 
     def loss_kernel(x, w):
-        return (vfl_matmul(x, w, off, bk=bk, interpret=True) * t).sum()
+        return (vfl_matmul(x, w, off, interpret=True) * t).sum()
 
     def loss_ref(x, w):
         return (vfl_matmul_ref(x, w, off) * t).sum()
@@ -76,7 +78,7 @@ def test_vfl_matmul_value_and_grad_under_jit_scan():
     def train(w):
         def body(w, x):
             def loss(w):
-                return (vfl_matmul(x, w, 28, bk=28) ** 2).sum()
+                return (vfl_matmul(x, w, 28) ** 2).sum()
             l, g = jax.value_and_grad(loss)(w)
             return w - 0.01 * g, l
         return jax.lax.scan(body, w, xs)
@@ -90,7 +92,7 @@ def test_vfl_matmul_value_and_grad_under_jit_scan():
     @jax.jit
     def one(w):
         def loss(w):
-            return (vfl_matmul(xs[0], w, 28, bk=28) ** 2).sum()
+            return (vfl_matmul(xs[0], w, 28) ** 2).sum()
         return w - 0.01 * jax.grad(loss)(w)
     allclose(one(w), w_ref, jnp.float32)
 

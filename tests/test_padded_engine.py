@@ -46,7 +46,6 @@ def test_layout_pad_structure():
     assert (pad.n_real, pad.n_clients) == (3, 7)
     assert pad.sizes == lay.sizes + (0,) * 4
     assert pad.offsets == lay.offsets + (0,) * 4
-    assert pad.block == lay.block
     # live rows identical, dead rows all-zero
     np.testing.assert_array_equal(pad.masks()[:3], lay.masks())
     assert pad.masks()[3:].sum() == 0
@@ -118,23 +117,23 @@ def test_vfl_matmul_gate():
     g = jnp.asarray(rng.normal(size=(8, 8)).astype(np.float32))
 
     def loss(x, w, gate):
-        return (vfl_matmul(x, w, 4, gate=gate, bk=4) * g).sum()
+        return (vfl_matmul(x, w, 4, gate=gate) * g).sum()
 
-    y_plain = vfl_matmul(x, w, 4, bk=4)
+    y_plain = vfl_matmul(x, w, 4)
     # gate=1.0 is a bitwise no-op on y and both grads
     np.testing.assert_array_equal(
-        np.asarray(vfl_matmul(x, w, 4, gate=jnp.float32(1.0), bk=4)),
+        np.asarray(vfl_matmul(x, w, 4, gate=jnp.float32(1.0))),
         np.asarray(y_plain))
     dx1, dw1 = jax.grad(loss, argnums=(0, 1))(x, w, jnp.float32(1.0))
     dx0, dw0 = jax.grad(loss, argnums=(0, 1))(
         x, w, jnp.float32(0.0))
-    dxp, dwp = jax.grad(lambda x, w: (vfl_matmul(x, w, 4, bk=4)
+    dxp, dwp = jax.grad(lambda x, w: (vfl_matmul(x, w, 4)
                                       * g).sum(), argnums=(0, 1))(x, w)
     np.testing.assert_array_equal(np.asarray(dx1), np.asarray(dxp))
     np.testing.assert_array_equal(np.asarray(dw1), np.asarray(dwp))
     # gate=0.0: y, dx, and the dW scatter rows are all exact zeros
     assert float(np.abs(np.asarray(
-        vfl_matmul(x, w, 4, gate=jnp.float32(0.0), bk=4))).max()) == 0.0
+        vfl_matmul(x, w, 4, gate=jnp.float32(0.0)))).max()) == 0.0
     assert float(np.abs(np.asarray(dx0)).max()) == 0.0
     assert float(np.abs(np.asarray(dw0)).max()) == 0.0
     # ungated dW only ever touches the client's row block
