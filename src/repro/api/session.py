@@ -264,8 +264,9 @@ class Session:
         self._fed = None
         self._runner = None
         self._last_params = None
-        # host-side span tracer: armed with the in-scan taps (obs !=
-        # "none"), the zero-overhead NullTracer otherwise
+        # host-side span tracer: its spans reach a capturing profiler
+        # at every obs level; the in-memory record (SpanTracer) is
+        # armed with the in-scan taps (obs != "none")
         self.tracer = SpanTracer() if spec.obs != "none" \
             else NullTracer()
 
@@ -281,7 +282,8 @@ class Session:
             with self.tracer.span("build", cat="setup",
                                   dataset=self.spec.dataset):
                 self._fed = DeVertiFL(
-                    _protocol_config(self.spec, self.mode.internal))
+                    _protocol_config(self.spec, self.mode.internal),
+                    tracer=self.tracer)
         return self._fed
 
     def _result(self, metrics, history, params, telemetry,
@@ -567,18 +569,23 @@ class Session:
             f"retry must be 'auto', None/False, or a RetryPolicy; got "
             f"{type(retry).__name__}")
 
-    def _run_federated(self, key=None, start_round=0, state=None,
-                       resumed_from=None, retry="auto") -> RunResult:
+    def _run_federated(self, **kw) -> RunResult:
+        with self.tracer.span("run", cat="run"):
+            return self._train_federated(**kw)
+
+    def _train_federated(self, key=None, start_round=0, state=None,
+                         resumed_from=None, retry="auto") -> RunResult:
         spec = self.spec
         fed = self.federation
         policy = self._retry_policy(retry)
         key = key if key is not None else jax.random.PRNGKey(spec.seed)
         init_key, loop_key = train_keys(key)
         if state is None:
-            params = fed.init_params(init_key)
-            opt_state = jax.vmap(fed.opt.init)(params)
-            step_idx = jnp.zeros((), jnp.int32)
-            sched_state = fed.init_sched_state()
+            with self.tracer.span("init", cat="setup"):
+                params = fed.init_params(init_key)
+                opt_state = jax.vmap(fed.opt.init)(params)
+                step_idx = jnp.zeros((), jnp.int32)
+                sched_state = fed.init_sched_state()
         else:
             params, opt_state, step_idx, sched_state = state
         history = []

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from repro.analysis.barrier import tag
 
 
+@jax.named_scope("exchange")
 def hidden_output_exchange(h_all, differentiable=False, client_mask=None):
     """h_all: [n_clients, B, H] per-client hidden outputs.
 
@@ -53,6 +54,7 @@ def hidden_output_exchange(h_all, differentiable=False, client_mask=None):
     return h_all + peers
 
 
+@jax.named_scope("exchange")
 def scheduled_exchange(h_all, h_ref, eff_mask):
     """Exchange where the broadcast tensors come from a schedule's
     reference stack (repro.schedule): client i consumes its OWN
@@ -107,6 +109,7 @@ def screen_exchange(payload, last_good, max_abs):
     return jnp.where(sel, last_good, payload), bad
 
 
+@jax.named_scope("exchange")
 def select_cached_exchange(h_fresh, h_cached, use_cached):
     """Serving-path cache splice (repro.serving.federated): per-slot
     SELECT between a freshly computed exchange-point stack and one

@@ -350,6 +350,7 @@ def _ce(logits, labels):
     return -jnp.take_along_axis(logp, labels[:, None], axis=-1).mean()
 
 
+@jax.named_scope("loss")
 def _masked_mean(values, client_mask):
     """Mean over live clients: sum(v * mask) * (1/n_live).  The sum is
     a left fold over the static client axis, so a dead tail of exact
@@ -363,6 +364,7 @@ def _masked_mean(values, client_mask):
     return total * (1.0 / client_mask.sum())
 
 
+@jax.named_scope("exchange")
 def _masked_hidden_sum(h_all, client_mask):
     """[n, B, H] -> [B, H] exchange sum excluding dead clients (their
     terms are exact +0.0, preserving the unpadded reduction bits)."""
@@ -470,9 +472,10 @@ def make_step_fn(model, opt, pcfg, layout=None, first_layer_fn=None):
     through = partial(rest, model, pcfg.exchange_at)
 
     def update(params, opt_state, grads, step_idx):
-        params, opt_state, _ = jax.vmap(
-            lambda g, s, p: opt.update(g, s, p, step_idx))(
-                grads, opt_state, params)
+        with jax.named_scope("optimizer"):
+            params, opt_state, _ = jax.vmap(
+                lambda g, s, p: opt.update(g, s, p, step_idx))(
+                    grads, opt_state, params)
         return params, opt_state
 
     if fl == "masked":
@@ -535,19 +538,24 @@ def make_step_fn(model, opt, pcfg, layout=None, first_layer_fn=None):
         hidden_from = partial(client_hidden_from, model, pcfg.exchange_at)
 
         def losses_fn(ps, lay, xb, yb, differentiable=None):
-            h1 = first(ps, xb, lay)
-            h_all = jax.vmap(hidden_from)(ps, h1)
+            with jax.named_scope("first_layer"):
+                h1 = first(ps, xb, lay)
+            with jax.named_scope("tower"):
+                h_all = jax.vmap(hidden_from)(ps, h1)
             if differentiable is not None:
                 h_all = hidden_output_exchange(
                     h_all, differentiable=differentiable,
                     client_mask=lay.client_mask)
-            logits = jax.vmap(through)(ps, h_all)
-            return jax.vmap(_ce, in_axes=(0, None))(logits, yb)   # [n]
+            with jax.named_scope("tower"):
+                logits = jax.vmap(through)(ps, h_all)
+            with jax.named_scope("loss"):
+                return jax.vmap(_ce, in_axes=(0, None))(logits, yb)  # [n]
 
         def devertifl_step(params, opt_state, lay, xb, yb, step_idx):
             def total(ps):
                 losses = losses_fn(ps, lay, xb, yb, differentiable=False)
-                return (losses * lay.client_mask).sum(), losses
+                with jax.named_scope("loss"):
+                    return (losses * lay.client_mask).sum(), losses
 
             grads, losses = jax.grad(total, has_aux=True)(params)
             params, opt_state = update(params, opt_state, grads, step_idx)
@@ -556,7 +564,8 @@ def make_step_fn(model, opt, pcfg, layout=None, first_layer_fn=None):
         def nonfed_step(params, opt_state, lay, xb, yb, step_idx):
             def total(ps):
                 losses = losses_fn(ps, lay, xb, yb)
-                return (losses * lay.client_mask).sum(), losses
+                with jax.named_scope("loss"):
+                    return (losses * lay.client_mask).sum(), losses
 
             grads, losses = jax.grad(total, has_aux=True)(params)
             params, opt_state = update(params, opt_state, grads, step_idx)
@@ -681,8 +690,9 @@ def make_round_fn(model, opt, pcfg, n_train, fedavg_fn=None, layout=None,
 
             def body(carry, batch_idx):
                 params, opt_state, step_idx = carry
-                xb = jnp.take(xtr, batch_idx, axis=0)
-                yb = jnp.take(ytr, batch_idx, axis=0)
+                with jax.named_scope("batch"):
+                    xb = jnp.take(xtr, batch_idx, axis=0)
+                    yb = jnp.take(ytr, batch_idx, axis=0)
                 params, opt_state, loss = step(params, opt_state, lay,
                                                xb, yb, step_idx)
                 return (params, opt_state, step_idx + 1), loss
@@ -690,7 +700,9 @@ def make_round_fn(model, opt, pcfg, n_train, fedavg_fn=None, layout=None,
             (params, opt_state, step_idx), losses = jax.lax.scan(
                 body, (params, opt_state, step_idx), idx)
             if do_fedavg:
-                params = call_fedavg(fedavg_fn, params, lay.client_mask)
+                with jax.named_scope("fedavg"):
+                    params = call_fedavg(fedavg_fn, params,
+                                         lay.client_mask)
             return params, opt_state, step_idx, sched_state, losses
 
         return round_fn
@@ -718,8 +730,9 @@ def make_round_fn(model, opt, pcfg, n_train, fedavg_fn=None, layout=None,
 
         def body(carry, batch_idx):
             params, opt_state, step_idx, sched_state = carry
-            xb = jnp.take(xtr, batch_idx, axis=0)
-            yb = jnp.take(ytr, batch_idx, axis=0)
+            with jax.named_scope("batch"):
+                xb = jnp.take(xtr, batch_idx, axis=0)
+                yb = jnp.take(ytr, batch_idx, axis=0)
             params, opt_state, sched_state, loss = step(
                 params, opt_state, lay, eff_mask, sched_state, xb, yb,
                 step_idx)
@@ -734,7 +747,8 @@ def make_round_fn(model, opt, pcfg, n_train, fedavg_fn=None, layout=None,
             fam = getattr(impl, "fedavg_mask", None)
             mask = eff_mask if fam is None else fam(sched_state,
                                                     eff_mask)
-            params = call_fedavg(fedavg_fn, params, mask)
+            with jax.named_scope("fedavg"):
+                params = call_fedavg(fedavg_fn, params, mask)
         sched_state = impl.round_end(sched_state)
         return params, opt_state, step_idx, sched_state, losses
 
@@ -756,13 +770,17 @@ def make_h_all_fn(model, pcfg, layout=None, first_layer_fn=None):
 
         def h_all_fn(params, x, lay):
             xm = x[None] * lay.masks[:, None, :]
-            return jax.vmap(hidden)(params, xm)
+            with jax.named_scope("tower"):
+                return jax.vmap(hidden)(params, xm)
     else:
         first = first_layer_fn or make_first_layer_fn(model, pcfg, layout)
         hidden_from = partial(client_hidden_from, model, pcfg.exchange_at)
 
         def h_all_fn(params, x, lay):
-            return jax.vmap(hidden_from)(params, first(params, x, lay))
+            with jax.named_scope("first_layer"):
+                h1 = first(params, x, lay)
+            with jax.named_scope("tower"):
+                return jax.vmap(hidden_from)(params, h1)
 
     return h_all_fn
 
@@ -781,7 +799,8 @@ def make_predict_fn(model, pcfg, layout=None, first_layer_fn=None):
         if pcfg.mode in ("devertifl", "verticomb"):
             h_all = hidden_output_exchange(h_all, differentiable=False,
                                            client_mask=lay.client_mask)
-        logits = jax.vmap(through)(params, h_all)   # [n, B, C]
+        with jax.named_scope("tower"):
+            logits = jax.vmap(through)(params, h_all)   # [n, B, C]
         return jnp.argmax(logits, axis=-1)          # per-client preds
 
     return predict
@@ -822,9 +841,14 @@ class DeVertiFL:
     and re-expresses them itself.
     """
 
-    def __init__(self, pcfg: ProtocolConfig, fedavg_fn=None):
+    def __init__(self, pcfg: ProtocolConfig, fedavg_fn=None,
+                 tracer=None):
+        from repro.obs.trace import NullTracer
         self.pcfg = pcfg
         self._fedavg_fn = fedavg_fn
+        # host spans of evaluate (predict, score); the owning Session
+        # passes its own tracer
+        self.tracer = tracer if tracer is not None else NullTracer()
         self.mcfg = get_config(arch_for(pcfg.dataset))
         self.model = PaperMLP(self.mcfg)
         xtr, ytr, xte, yte = DR.make_dataset(pcfg.dataset, pcfg.n_samples,
@@ -935,12 +959,15 @@ class DeVertiFL:
     def evaluate(self, params):
         # the test set is already cached in canonical order; skip
         # predict()'s per-call permutation of raw inputs
-        preds = np.asarray(self._predict(params, self._xte, self._lay))
-        avg = "macro" if len(np.unique(self.ytr)) > 2 else "binary"
-        f1s = [f1_score(self.yte, preds[i], average=avg)
-               for i in range(self.pcfg.n_clients)]
-        accs = [accuracy(self.yte, preds[i])
-                for i in range(self.pcfg.n_clients)]
+        with self.tracer.span("predict", cat="eval"):
+            preds = np.asarray(self._predict(params, self._xte,
+                                             self._lay))
+        with self.tracer.span("score", cat="eval"):
+            avg = "macro" if len(np.unique(self.ytr)) > 2 else "binary"
+            f1s = [f1_score(self.yte, preds[i], average=avg)
+                   for i in range(self.pcfg.n_clients)]
+            accs = [accuracy(self.yte, preds[i])
+                    for i in range(self.pcfg.n_clients)]
         return {"f1": float(np.mean(f1s)), "acc": float(np.mean(accs)),
                 "f1_per_client": f1s}
 

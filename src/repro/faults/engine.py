@@ -164,7 +164,12 @@ class FaultImpl:
 
     def select(self, state, h_now):
         h_ref, inner = self.inner.select(state["inner"], h_now)
-        st = {**state, "inner": inner}
+        with jax.named_scope("guard"):
+            return self._guard({**state, "inner": inner}, h_ref, h_now)
+
+    def _guard(self, st, h_ref, h_now):
+        """Stragglers' delays, transport corruption and the screen of
+        the consumed stack, after the inner layer's select."""
         if self.max_delay > 0:
             # stragglers' consumed stacks are their own, d steps old
             # (ring read before push, the LaneScheduleImpl idiom)

@@ -10,11 +10,12 @@ Three layers (docs/ARCHITECTURE.md section 12):
              counts, bytes-on-wire, staleness depth.  Observation-only
              and hash-excluded: ``obs="full"`` trajectories are
              bitwise ``obs="none"`` trajectories.
-  trace      :class:`SpanTracer` host spans over build / round /
-             eval / checkpoint / serving request lifecycles, exported
-             as Chrome trace-event JSON (Perfetto-loadable).
-             ``obs="none"`` sessions get the zero-overhead
-             :class:`NullTracer`.
+  trace      host spans over run / build / init / round / eval /
+             checkpoint and the server's submit / offer / step, on a
+             capturing ``jax.profiler`` timeline at every level;
+             :class:`SpanTracer` also records them for Chrome
+             trace-event JSON (Perfetto-loadable), and ``obs="none"``
+             sessions get the non-recording :class:`NullTracer`.
   telemetry  :class:`Telemetry` -- the one versioned record on
              ``RunResult.telemetry`` / ``ServeReport.obs`` folding
              wall clock, fault/wire/serve counters, obs series and

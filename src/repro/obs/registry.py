@@ -6,12 +6,13 @@ An obs level is named by a compact spec string parsed against the
 
   none    no in-scan taps; the engine runs its untouched code path,
           bit-for-bit (the protocol never wraps the engine impl for
-          it), the host tracer is a no-op NullTracer, and the spec
+          it), the host tracer is the non-recording NullTracer (its
+          spans reach a capturing profiler only), and the spec
           hash is unchanged -- ``obs`` lives in ``HASH_EXCLUDE``
           because taps provably never change trajectories.
   basic   cheap per-round series recorded on device in the scan carry:
           masked-mean loss, guard-quarantine counts, bytes-on-wire,
-          staleness depth.  The host span tracer is armed.
+          staleness depth.  The host span tracer records.
   full    everything basic records plus the per-client series: L2
           norms of the released exchange stacks and per-client
           gradient norms.

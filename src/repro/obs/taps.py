@@ -162,11 +162,13 @@ class ObsImpl:
         # Recording it is a declared declassification -- the norms
         # leave the exchange flow for the host-readable series
         if self.static_level >= 2:
-            exn = tag(jnp.sqrt((h_ref * h_ref).sum(axis=(1, 2))),
-                      "declass", "obs")
-            st["o_exn"] = st["o_exn"] + st["full_on"] * exn
+            with jax.named_scope("taps"):
+                exn = tag(jnp.sqrt((h_ref * h_ref).sum(axis=(1, 2))),
+                          "declass", "obs")
+                st["o_exn"] = st["o_exn"] + st["full_on"] * exn
         return h_ref, st
 
+    @jax.named_scope("taps")
     def tap_step(self, state, losses, grads, lay):
         """The fifth (optional) hook: called by the step builder once
         per optimizer step, AFTER the update, with the per-client loss

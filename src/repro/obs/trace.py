@@ -1,32 +1,35 @@
 """Host-side span tracing: where the wall-clock time of a run or a
 serving session actually went.
 
-:class:`SpanTracer` records nested context-manager spans
-(``with tracer.span("round", cat="train", round=r): ...``) and point
-instants with microsecond wall-clock timestamps.  It is strictly a
-HOST-side instrument -- it never touches traced values, so arming it
-cannot perturb trajectories -- and its whole cost is two
-``perf_counter`` calls plus one dict append per span.
+Every ``with tracer.span("round", cat="train", round=r): ...`` reaches
+the ``jax.profiler`` timeline whenever a profiler is capturing, at any
+``obs`` level: the span opens a ``jax.profiler.TraceAnnotation`` named
+``devertifl.<name>`` on the host, on the same clock as the device's
+operations, so a device trace names the host's layers (run, init,
+round, eval, submit, step, ...) in its idle gaps.  When nothing
+captures, a span costs one ``TraceAnnotation.is_enabled()`` check, and
+its arguments are never formatted.
 
-Exports:
+:class:`NullTracer` (``obs="none"``) does only that.  :class:`SpanTracer`
+(``obs != "none"``) also keeps an in-memory record of every closed
+span with microsecond ``perf_counter`` timestamps from its creation:
 
   export(path)   Chrome trace-event JSON (the ``{"traceEvents":
-                 [...]}`` container of "X" complete events + "i"
-                 instants) -- loadable in Perfetto / chrome://tracing.
+                 [...]}`` container of "X" complete events) --
+                 loadable in Perfetto / chrome://tracing.
   summary()      a human-readable per-span-name aggregate table
                  (count, total ms, mean ms, share of traced wall).
   to_records()   the raw span dicts, JSON-safe -- what the unified
                  Telemetry record embeds.
 
-:class:`NullTracer` is the ``obs="none"`` stand-in: every method is a
-no-op (``span`` returns one shared nullcontext), so instrumented call
-sites cost one attribute lookup when tracing is off -- the
-zero-overhead-when-off invariant (docs/ARCHITECTURE.md section 12).
+Both are strictly HOST-side instruments -- they never touch traced
+values, so tracing cannot perturb trajectories (docs/ARCHITECTURE.md
+section 12).
 
 ``profile_to(dir)`` optionally brackets a region with
-``jax.profiler.start_trace/stop_trace`` so a device-level profile can
-be captured alongside the host spans; a profiler that cannot start
-raises rather than leaving a run without the trace it asked for.
+``jax.profiler.start_trace/stop_trace`` so a device-level profile is
+captured with the spans in it; a profiler that cannot start raises
+rather than leaving a run without the trace it asked for.
 """
 from __future__ import annotations
 
@@ -37,45 +40,29 @@ import time
 from contextlib import contextmanager
 from typing import List, Optional
 
+from jax.profiler import TraceAnnotation
 
-class SpanTracer:
-    """Nested wall-clock spans with Chrome trace-event export."""
+# prefix of every span's name on the profiler's timeline
+PREFIX = "devertifl."
+_capturing = TraceAnnotation.is_enabled
 
-    active = True
 
-    def __init__(self):
-        self.records: List[dict] = []   # closed spans + instants
-        self._depth = 0
-        self._t0 = time.perf_counter()
-        self._pid = os.getpid()
+class NullTracer:
+    """The ``obs="none"`` tracer: records nothing.  A span reaches the
+    profiler when one is capturing and is one shared nullcontext
+    otherwise, so an instrumented call site costs a method call and
+    one ``is_enabled`` check when tracing is off."""
 
-    # ------------------------------------------------------------------
-    def _us(self, t: float) -> float:
-        return (t - self._t0) * 1e6
+    active = False
+    _null = contextlib.nullcontext()
 
-    @contextmanager
     def span(self, name: str, cat: str = "run", **args):
-        """Record one nested span around the with-body."""
-        depth = self._depth
-        self._depth += 1
-        t_in = time.perf_counter()
-        try:
-            yield
-        finally:
-            t_out = time.perf_counter()
-            self._depth = depth
-            self.records.append({
-                "name": name, "cat": cat, "ph": "X",
-                "ts": self._us(t_in),
-                "dur": (t_out - t_in) * 1e6,
-                "depth": depth, "args": args})
-
-    def instant(self, name: str, cat: str = "run", **args):
-        """Record a point event (a request lifecycle edge)."""
-        self.records.append({
-            "name": name, "cat": cat, "ph": "i",
-            "ts": self._us(time.perf_counter()),
-            "dur": 0.0, "depth": self._depth, "args": args})
+        """The profiler annotation ``devertifl.<name>`` around the
+        with-body while a profiler captures; ``args`` are recorded by
+        :class:`SpanTracer` only."""
+        if _capturing():
+            return TraceAnnotation(PREFIX + name)
+        return self._null
 
     @contextmanager
     def profile_to(self, profile_dir: Optional[str]):
@@ -95,9 +82,57 @@ class SpanTracer:
         finally:
             jax.profiler.stop_trace()
 
+    def to_records(self) -> List[dict]:
+        return []
+
+    def export(self, path: str):
+        raise ValueError(
+            "tracing is off (obs='none' builds a NullTracer); build "
+            "the session with spec.obs='basic' or 'full' to record "
+            "spans")
+
+    def summary(self) -> str:
+        return "tracing off (obs='none')"
+
+
+class SpanTracer(NullTracer):
+    """The recording tracer: nested wall-clock spans, kept in memory
+    for Chrome trace-event export, that reach the profiler too."""
+
+    active = True
+
+    def __init__(self):
+        self.records: List[dict] = []   # closed spans
+        self._depth = 0
+        self._t0 = time.perf_counter()
+        self._pid = os.getpid()
+
+    # ------------------------------------------------------------------
+    def _us(self, t: float) -> float:
+        return (t - self._t0) * 1e6
+
+    @contextmanager
+    def span(self, name: str, cat: str = "run", **args):
+        """Record one nested span around the with-body."""
+        depth = self._depth
+        self._depth += 1
+        mark = NullTracer.span(self, name)
+        t_in = time.perf_counter()
+        try:
+            with mark:
+                yield
+        finally:
+            t_out = time.perf_counter()
+            self._depth = depth
+            self.records.append({
+                "name": name, "cat": cat, "ph": "X",
+                "ts": self._us(t_in),
+                "dur": (t_out - t_in) * 1e6,
+                "depth": depth, "args": args})
+
     # ------------------------------------------------------------------
     def to_records(self) -> List[dict]:
-        """The raw span/instant dicts (JSON-safe; args stringified)."""
+        """The raw span dicts (JSON-safe; args stringified)."""
         return [{**r, "args": {k: _safe(v)
                                for k, v in r["args"].items()}}
                 for r in self.records]
@@ -106,16 +141,10 @@ class SpanTracer:
         """Write Chrome trace-event JSON (Perfetto-loadable); returns
         ``path``.  Spans map to "X" complete events on one pid/tid so
         the viewer reconstructs the nesting from ts/dur containment."""
-        events = []
-        for r in self.to_records():
-            ev = {"name": r["name"], "cat": r["cat"], "ph": r["ph"],
-                  "ts": r["ts"], "pid": self._pid, "tid": 1,
-                  "args": r["args"]}
-            if r["ph"] == "X":
-                ev["dur"] = r["dur"]
-            else:
-                ev["s"] = "t"       # instant scope: thread
-            events.append(ev)
+        events = [{"name": r["name"], "cat": r["cat"], "ph": r["ph"],
+                   "ts": r["ts"], "dur": r["dur"], "pid": self._pid,
+                   "tid": 1, "args": r["args"]}
+                  for r in self.to_records()]
         blob = {"traceEvents": events, "displayTimeUnit": "ms"}
         d = os.path.dirname(os.path.abspath(path))
         if d:
@@ -126,7 +155,7 @@ class SpanTracer:
 
     def summary(self) -> str:
         """Per-span-name aggregate table over the recorded spans."""
-        spans = [r for r in self.records if r["ph"] == "X"]
+        spans = self.records
         if not spans:
             return "no spans recorded"
         agg = {}
@@ -144,36 +173,6 @@ class SpanTracer:
                          f"{tot / n / 1e3:>9.3f} "
                          f"{min(tot / wall, 1.0):>5.0%}")
         return "\n".join(lines)
-
-
-class NullTracer:
-    """The ``obs="none"`` tracer: every method is a no-op.  ``span``
-    hands back one shared nullcontext, so an instrumented call site
-    costs an attribute lookup and nothing else."""
-
-    active = False
-    _null = contextlib.nullcontext()
-
-    def span(self, name: str, cat: str = "run", **args):
-        return self._null
-
-    def profile_to(self, profile_dir):
-        return self._null
-
-    def instant(self, name: str, cat: str = "run", **args):
-        pass
-
-    def to_records(self) -> List[dict]:
-        return []
-
-    def export(self, path: str):
-        raise ValueError(
-            "tracing is off (obs='none' builds a NullTracer); build "
-            "the session with spec.obs='basic' or 'full' to record "
-            "spans")
-
-    def summary(self) -> str:
-        return "tracing off (obs='none')"
 
 
 def _safe(v):

@@ -202,9 +202,10 @@ def make_sched_step_fn(model, opt, pcfg, impl, layout=None,
     through = partial(P.rest, model, pcfg.exchange_at)
 
     def update(params, opt_state, grads, step_idx):
-        params, opt_state, _ = jax.vmap(
-            lambda g, s, p: opt.update(g, s, p, step_idx))(
-                grads, opt_state, params)
+        with jax.named_scope("optimizer"):
+            params, opt_state, _ = jax.vmap(
+                lambda g, s, p: opt.update(g, s, p, step_idx))(
+                    grads, opt_state, params)
         return params, opt_state
 
     # fifth (optional) impl hook: obs taps record the loss vector and
@@ -244,7 +245,10 @@ def make_sched_step_fn(model, opt, pcfg, impl, layout=None,
                               pcfg.exchange_at)
 
         def h_all_fn(ps, lay, xb):
-            return jax.vmap(hidden_from)(ps, first(ps, xb, lay))
+            with jax.named_scope("first_layer"):
+                h1 = first(ps, xb, lay)
+            with jax.named_scope("tower"):
+                return jax.vmap(hidden_from)(ps, h1)
 
         if getattr(impl, "identity_select", False):
             # depth-0 select statically returns h_now, so the
@@ -262,11 +266,13 @@ def make_sched_step_fn(model, opt, pcfg, impl, layout=None,
                     h_all = h_all_fn(ps, lay, xb)
                     h_now = jax.lax.stop_gradient(h_all)
                     h = scheduled_exchange(h_all, h_now, eff_mask)
-                    logits = jax.vmap(through)(ps, h)
-                    losses = jax.vmap(P._ce, in_axes=(0, None))(
-                        logits, yb)
-                    return ((losses * lay.client_mask).sum(),
-                            (losses, h_now))
+                    with jax.named_scope("tower"):
+                        logits = jax.vmap(through)(ps, h)
+                    with jax.named_scope("loss"):
+                        losses = jax.vmap(P._ce, in_axes=(0, None))(
+                            logits, yb)
+                        return ((losses * lay.client_mask).sum(),
+                                (losses, h_now))
 
                 grads, (losses, h_now) = jax.grad(
                     total, has_aux=True)(params)
@@ -288,9 +294,12 @@ def make_sched_step_fn(model, opt, pcfg, impl, layout=None,
             def total(ps):
                 h = scheduled_exchange(h_all_fn(ps, lay, xb), h_ref,
                                        eff_mask)
-                logits = jax.vmap(through)(ps, h)
-                losses = jax.vmap(P._ce, in_axes=(0, None))(logits, yb)
-                return (losses * lay.client_mask).sum(), losses
+                with jax.named_scope("tower"):
+                    logits = jax.vmap(through)(ps, h)
+                with jax.named_scope("loss"):
+                    losses = jax.vmap(P._ce, in_axes=(0, None))(logits,
+                                                                yb)
+                    return (losses * lay.client_mask).sum(), losses
 
             grads, losses = jax.grad(total, has_aux=True)(params)
             params, opt_state = update(params, opt_state, grads,

@@ -240,12 +240,14 @@ def make_serve_step_fn(model, pcfg, layout, first_layer_fn=None):
     def step(params, x, h_cached, use_cached, slot_mask, lay):
         h_fresh = h_all_fn(params, x, lay)
         if not plan.is_none:
-            h_fresh = wire_apply_static(plan, h_fresh)
+            with jax.named_scope("wire"):
+                h_fresh = wire_apply_static(plan, h_fresh)
         h_all = select_cached_exchange(h_fresh, h_cached, use_cached)
         h_ex = hidden_output_exchange(
             h_all, differentiable=False,
             client_mask=lay.client_mask) if exchange else h_all
-        logits = jax.vmap(through)(params, h_ex)   # [n, S, C]
+        with jax.named_scope("tower"):
+            logits = jax.vmap(through)(params, h_ex)   # [n, S, C]
         preds = jnp.argmax(logits, axis=-1)        # [n, S]
         preds = jnp.where(slot_mask[None, :] != 0, preds, -1)
         return preds, h_all
@@ -271,8 +273,9 @@ class FederatedServer:
                  cache=128, overflow: str = "reject",
                  first_layer_fn=None, tracer=None):
         from repro.obs import NullTracer
-        # request-lifecycle instants + step spans; the NullTracer
-        # default keeps the pre-obs serving path instrument-free
+        # submit / offer / step spans (step: admit, upload, fetch,
+        # complete); the NullTracer default records nothing and marks
+        # the profiler's timeline only while one captures
         self.tracer = tracer if tracer is not None else NullTracer()
         if max_slots < 1:
             raise ValueError(f"max_slots must be >= 1, got {max_slots}")
@@ -386,65 +389,64 @@ class FederatedServer:
                             f"{type(req).__name__}")
         if req.uid in self._info:
             raise ValueError(f"duplicate request uid {req.uid!r}")
-        now = time.perf_counter()
-        if self._t0 is None:
-            self._t0 = now
-        rec = {"uid": req.uid, "entity_id": req.entity_id,
-               "t_submit": now, "status": "assembling",
-               "cached": False, "slices": {}}
-        self._info[req.uid] = rec
-        self._assembly[req.uid] = rec
-        self.submitted += 1
-        self.tracer.instant("submit", cat="serve", uid=str(req.uid))
-        if self.cache is not None:
-            h = self.cache.lookup((self.spec_hash, req.entity_id))
-            if h is not None:
-                rec["cached"] = True
-                rec["_h"] = h
-                del self._assembly[req.uid]
-                self._to_ready(rec)
-                return req.uid
-        for client, payload in (req.slices or {}).items():
-            self.offer(req.uid, client, payload)
-        return req.uid
+        with self.tracer.span("submit", cat="serve"):
+            now = time.perf_counter()
+            if self._t0 is None:
+                self._t0 = now
+            rec = {"uid": req.uid, "entity_id": req.entity_id,
+                   "t_submit": now, "status": "assembling",
+                   "cached": False, "slices": {}}
+            self._info[req.uid] = rec
+            self._assembly[req.uid] = rec
+            self.submitted += 1
+            if self.cache is not None:
+                h = self.cache.lookup((self.spec_hash, req.entity_id))
+                if h is not None:
+                    rec["cached"] = True
+                    rec["_h"] = h
+                    del self._assembly[req.uid]
+                    self._to_ready(rec)
+                    return req.uid
+            for client, payload in (req.slices or {}).items():
+                self.offer(req.uid, client, payload)
+            return req.uid
 
     def offer(self, uid, client: int, payload):
         """Deliver one client's canonical column slice for a pending
         request.  Order is free -- readiness fires when the LAST live
         client delivers, whoever that is (arrival-order invariance is
         pinned in tests/test_serving.py)."""
-        rec = self._info.get(uid)
-        if rec is None:
-            raise KeyError(f"offer() for unknown request uid {uid!r}; "
-                           "submit() it first")
-        if rec["status"] != "assembling":
-            # cache-hit / queued / in-flight requests need no slices;
-            # late deliveries are dropped silently (the federated
-            # analog of a straggler's payload arriving after the
-            # round already served the request)
-            return
-        if not 0 <= client < self.n_live:
-            raise ValueError(f"client {client} out of range for "
-                             f"{self.n_live} live clients")
-        payload = np.asarray(payload, np.float32).reshape(-1)
-        want = self._sizes[client]
-        if payload.shape != (want,):
-            raise ValueError(
-                f"request {uid!r}: client {client}'s slice must have "
-                f"{want} features (Layout.sizes[{client}]), got "
-                f"{payload.shape}")
-        rec["slices"][client] = payload
-        self.tracer.instant("offer", cat="serve", uid=str(uid),
-                            client=client)
-        if len(rec["slices"]) == self.n_live:
-            x = np.zeros((self._F,), np.float32)
-            for i, sl in rec["slices"].items():
-                x[self._offsets[i]:self._offsets[i]
-                  + self._sizes[i]] = sl
-            rec["_x"] = x
-            del rec["slices"]
-            del self._assembly[uid]
-            self._to_ready(rec)
+        with self.tracer.span("offer", cat="serve"):
+            rec = self._info.get(uid)
+            if rec is None:
+                raise KeyError(f"offer() for unknown request uid "
+                               f"{uid!r}; submit() it first")
+            if rec["status"] != "assembling":
+                # cache-hit / queued / in-flight requests need no
+                # slices; late deliveries are dropped silently (the
+                # federated analog of a straggler's payload arriving
+                # after the round already served the request)
+                return
+            if not 0 <= client < self.n_live:
+                raise ValueError(f"client {client} out of range for "
+                                 f"{self.n_live} live clients")
+            payload = np.asarray(payload, np.float32).reshape(-1)
+            want = self._sizes[client]
+            if payload.shape != (want,):
+                raise ValueError(
+                    f"request {uid!r}: client {client}'s slice must "
+                    f"have {want} features (Layout.sizes[{client}]), "
+                    f"got {payload.shape}")
+            rec["slices"][client] = payload
+            if len(rec["slices"]) == self.n_live:
+                x = np.zeros((self._F,), np.float32)
+                for i, sl in rec["slices"].items():
+                    x[self._offsets[i]:self._offsets[i]
+                      + self._sizes[i]] = sl
+                rec["_x"] = x
+                del rec["slices"]
+                del self._assembly[uid]
+                self._to_ready(rec)
 
     def _to_ready(self, rec):
         """Move an assembled (or cache-hit) request to the bounded
@@ -463,9 +465,6 @@ class FederatedServer:
             self.evicted.append(old)
         rec["status"] = "ready"
         self._ready.append(rec["uid"])
-        self.tracer.instant("ready", cat="serve",
-                            uid=str(rec["uid"]),
-                            cached=bool(rec["cached"]))
 
     # ------------------------------------------------------------------
     def _admit(self):
@@ -480,8 +479,6 @@ class FederatedServer:
             rec["t_admit"] = time.perf_counter()
             rec["status"] = "in_flight"
             self.admission_log.append(uid)
-            self.tracer.instant("admit", cat="serve", uid=str(uid),
-                                slot=s)
             self._slots[s] = uid
             self._mbuf[s] = 1.0
             if rec["cached"]:
@@ -502,18 +499,24 @@ class FederatedServer:
         jitted batched step, complete and free them.  Returns the
         number of requests completed (0 when nothing was admissible).
         """
-        self._admit()
-        if self.occupancy == 0:
-            return 0
-        with self.tracer.span("serve_step", cat="serve",
-                              occupancy=self.occupancy):
-            preds, h_all = self._step_fn(
-                self.params, jnp.asarray(self._xbuf),
-                jnp.asarray(self._hbuf), jnp.asarray(self._ubuf),
-                jnp.asarray(self._mbuf), self._lay)
-            preds = np.asarray(preds)
-            h_all = np.asarray(h_all)
-        self.steps += 1
+        with self.tracer.span("step", cat="serve"):
+            with self.tracer.span("admit", cat="serve"):
+                self._admit()
+            if self.occupancy == 0:
+                return 0
+            with self.tracer.span("upload", cat="serve"):
+                args = (jnp.asarray(self._xbuf), jnp.asarray(self._hbuf),
+                        jnp.asarray(self._ubuf), jnp.asarray(self._mbuf))
+            preds, h_all = self._step_fn(self.params, *args, self._lay)
+            with self.tracer.span("fetch", cat="serve"):
+                preds = np.asarray(preds)
+                h_all = np.asarray(h_all)
+            self.steps += 1
+            with self.tracer.span("complete", cat="serve"):
+                return self._complete(preds, h_all)
+
+    def _complete(self, preds, h_all) -> int:
+        """Record the step's results, fill the cache, free the slots."""
         done = 0
         now = time.perf_counter()
         for s, uid in enumerate(self._slots):
@@ -525,9 +528,6 @@ class FederatedServer:
             rec["latency_s"] = now - rec["t_submit"]
             rec["queue_s"] = rec["t_admit"] - rec["t_ready"]
             rec["status"] = "done"
-            self.tracer.instant("complete", cat="serve",
-                                uid=str(uid),
-                                latency_ms=rec["latency_s"] * 1e3)
             if self.cache is not None and not rec["cached"]:
                 h_slot = h_all[:, s, :].copy()
                 if not self._plan.is_none:

@@ -113,22 +113,23 @@ class WireImpl:
 
     def select(self, state, h_now):
         st = dict(state)
-        skey = jax.random.fold_in(st["wkey"], st["wstep"])
-        h_tx = wire_apply(h_now, skey,
-                          topk_on=st["topk_on"], topk_p=st["topk_p"],
-                          int8_on=st["int8_on"], dp_on=st["dp_on"],
-                          dp_sigma=st["dp_sigma"])
-        # the declared release point: everything downstream of this tag
-        # (rings, guards, the exchange sum) consumes wire data, never a
-        # raw hidden -- the taint auditor's proof obligation
-        h_tx = tag(h_tx, "declass", "wire")
-        raw_b, enc_b = wire_bytes(
-            st["live_n"], self.batch_size, self.width,
-            topk_on=st["topk_on"], topk_p=st["topk_p"],
-            int8_on=st["int8_on"])
-        st["wstep"] = st["wstep"] + 1
-        st["raw_bytes"] = st["raw_bytes"] + raw_b
-        st["enc_bytes"] = st["enc_bytes"] + enc_b
+        with jax.named_scope("wire"):
+            skey = jax.random.fold_in(st["wkey"], st["wstep"])
+            h_tx = wire_apply(h_now, skey,
+                              topk_on=st["topk_on"], topk_p=st["topk_p"],
+                              int8_on=st["int8_on"], dp_on=st["dp_on"],
+                              dp_sigma=st["dp_sigma"])
+            # the declared release point: everything downstream of this
+            # tag (rings, guards, the exchange sum) consumes wire data,
+            # never a raw hidden -- the taint auditor's proof obligation
+            h_tx = tag(h_tx, "declass", "wire")
+            raw_b, enc_b = wire_bytes(
+                st["live_n"], self.batch_size, self.width,
+                topk_on=st["topk_on"], topk_p=st["topk_p"],
+                int8_on=st["int8_on"])
+            st["wstep"] = st["wstep"] + 1
+            st["raw_bytes"] = st["raw_bytes"] + raw_b
+            st["enc_bytes"] = st["enc_bytes"] + enc_b
         h_ref, st["inner"] = self.inner.select(st["inner"], h_tx)
         return h_ref, st
 

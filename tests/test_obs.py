@@ -15,7 +15,10 @@ Contracts pinned here (docs/ARCHITECTURE.md section 12):
     (round_traces == 1) with every non-none lane bitwise equal to its
     "none" twin; per-cell series carry the [seeds, rounds, ...] axes
   * SpanTracer nesting/export (Chrome trace-event JSON) round-trips;
-    NullTracer is inert and refuses export
+    NullTracer records nothing and refuses export; at every obs level
+    the spans of Session.run and FederatedServer reach a capturing
+    profiler's host timeline, and the device programs carry their
+    named scopes (forward and backward) in the lowered HLO
   * the unified Telemetry record surfaces on RunResult.telemetry with
     the legacy ``timings`` dict derived from it; ServeReport.obs
     carries the serving copy and prometheus_text renders a valid
@@ -228,21 +231,24 @@ def test_span_tracer_nesting_export_and_summary(tmp_path):
     assert tr.active
     with tr.span("outer", cat="t"):
         with tr.span("inner", cat="t", round=1):
-            tr.instant("tick", x=2)
+            with tr.span("leaf", cat="t", x=2):
+                pass
     recs = tr.to_records()
     by = {r["name"]: r for r in recs}
     assert by["outer"]["depth"] == 0 and by["inner"]["depth"] == 1
+    assert by["leaf"]["depth"] == 2
     assert by["inner"]["args"]["round"] == 1
-    assert by["tick"]["ph"] == "i"
-    assert by["outer"]["dur"] >= by["inner"]["dur"] >= 0
+    assert by["leaf"]["args"]["x"] == 2
+    assert by["outer"]["dur"] >= by["inner"]["dur"] >= \
+        by["leaf"]["dur"] >= 0
+    assert not hasattr(tr, "instant")
     path = tr.export(str(tmp_path / "trace.json"))
     doc = json.load(open(path))
     assert doc["displayTimeUnit"] == "ms"
     evs = doc["traceEvents"]
-    assert {e["ph"] for e in evs} == {"X", "i"}
+    assert {e["ph"] for e in evs} == {"X"}
     for e in evs:                       # Perfetto-required fields
-        assert {"name", "ph", "ts", "pid", "tid"} <= set(e)
-        assert ("dur" in e) == (e["ph"] == "X")
+        assert {"name", "ph", "ts", "dur", "pid", "tid"} <= set(e)
     text = tr.summary()
     assert "outer" in text and "inner" in text
 
@@ -250,9 +256,11 @@ def test_span_tracer_nesting_export_and_summary(tmp_path):
 def test_null_tracer_is_inert_and_refuses_export():
     tr = NullTracer()
     assert not tr.active
-    with tr.span("x"):
-        tr.instant("y")
+    with tr.span("x", round=1):
+        with tr.span("y"):
+            pass
     assert tr.to_records() == []
+    assert not hasattr(tr, "instant")
     with pytest.raises(ValueError, match="obs"):
         tr.export("/tmp/never.json")
 
@@ -268,6 +276,112 @@ def test_session_tracer_spans_cover_the_run(tmp_path):
     assert json.load(open(path))["traceEvents"]
     # obs="none" sessions carry the no-op tracer
     assert not build(ExperimentSpec(**TINY)).tracer.active
+
+
+def _profiled_spans(directory):
+    """The ``devertifl.*`` event names on the host planes of the
+    profile written under ``directory``."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(directory, "**", "*.xplane.pb"),
+                      recursive=True)
+    data = ProfileData.from_file(path)
+    return [e.name for p in data.planes if p.name.startswith("/host:")
+            for line in p.lines for e in line.events
+            if e.name.startswith("devertifl.")]
+
+
+def test_spans_reach_the_profiler_at_obs_none(tmp_path):
+    sess = build(ExperimentSpec(**TINY))
+    with jax.profiler.trace(str(tmp_path / "run")):
+        res = sess.run()
+    names = _profiled_spans(str(tmp_path / "run"))
+    evals = TINY["rounds"] + 1                  # every round + final
+    assert names.count("devertifl.run") == 1
+    assert names.count("devertifl.init") == 1
+    assert names.count("devertifl.round") == TINY["rounds"]
+    for name in ("eval", "predict", "score"):
+        assert names.count("devertifl." + name) == evals
+    srv = sess.server(max_slots=2)
+    xte = np.asarray(sess.federation.xte)
+    with jax.profiler.trace(str(tmp_path / "serve")):
+        for i in range(3):
+            srv.submit(ServeRequest(uid=i, slices=split_features(
+                sess.federation.layout, xte[i])))
+        srv.run()
+    names = _profiled_spans(str(tmp_path / "serve"))
+    assert names.count("devertifl.submit") == 3
+    assert names.count("devertifl.offer") == 3 * TINY["n_clients"]
+    assert names.count("devertifl.step") >= 2
+    for name in ("admit", "upload", "fetch", "complete"):
+        assert "devertifl." + name in names
+    # the in-memory record stays off at obs="none"
+    assert sess.tracer.to_records() == []
+    assert res.telemetry.spans is None
+
+
+SCOPES = ("batch", "first_layer", "tower", "exchange", "loss",
+          "optimizer", "fedavg", "wire", "guard", "taps")
+
+
+def _scope_forms(lowered):
+    """scope -> the path components naming it in the lowered program's
+    locations (``first_layer``, ``jvp(first_layer)``, ...), and the
+    locations that name more than one scope."""
+    forms, both = {}, []
+    for loc in re.findall(r'loc\("([^"]*)"',
+                          lowered.as_text(debug_info=True)):
+        hit = set()
+        for part in loc.split("/"):
+            bare = part
+            while bare.endswith(")") and "(" in bare:
+                bare = bare[bare.index("(") + 1:-1]
+            if bare in SCOPES:
+                forms.setdefault(bare, set()).add(part)
+                hit.add(bare)
+        if len(hit) > 1:
+            both.append(loc)
+    return forms, both
+
+
+@pytest.mark.parametrize("first_layer", ["slice", "pallas"])
+@pytest.mark.parametrize("extra", [{}, {**STACK, "obs": "full"}],
+                         ids=["sync", "stack"])
+def test_device_programs_carry_named_scopes(first_layer, extra):
+    from repro.core.protocol import train_keys
+    from repro.serving.federated import FederatedServer
+    fed = build(ExperimentSpec(**TINY, first_layer=first_layer,
+                               **extra)).federation
+    init_key, loop_key = train_keys(jax.random.PRNGKey(0))
+    params = fed.init_params(init_key)
+    forms, both = _scope_forms(fed._round.lower(
+        params, jax.vmap(fed.opt.init)(params),
+        jax.numpy.zeros((), jax.numpy.int32), fed.init_sched_state(),
+        loop_key, fed._xtr, fed._ytr, fed._lay))
+    assert not both                      # no op is in two scopes
+    # forward and backward of the differentiated layers
+    for scope in ("first_layer", "tower", "loss"):
+        assert {f"jvp({scope})", f"transpose(jvp({scope}))"} <= \
+            forms[scope]
+    assert "jvp(exchange)" in forms["exchange"]
+    for scope in ("batch", "optimizer", "fedavg"):
+        assert forms[scope] == {scope}
+    machinery = {"wire", "guard", "taps"}
+    assert machinery <= set(forms) if extra else \
+        not machinery & set(forms)
+    # the inference programs: predict and the serving slot step
+    forms, both = _scope_forms(fed._predict.lower(params, fed._xte,
+                                                  fed._lay))
+    assert not both
+    assert {"first_layer", "tower", "exchange"} <= set(forms)
+    srv = FederatedServer(fed.model, fed.pcfg, fed.layout, params,
+                          max_slots=2)
+    forms, both = _scope_forms(srv._step_fn.lower(
+        params, srv._xbuf, srv._hbuf, srv._ubuf, srv._mbuf, srv._lay))
+    assert not both
+    assert {"first_layer", "tower", "exchange"} <= set(forms)
+    assert ("wire" in forms) == bool(extra)
 
 
 # ---------------------------------------------------------------------------
@@ -324,9 +438,18 @@ def test_serve_report_carries_unified_obs_record(served):
     assert obs["serve"]["completed"] == rep.counters["completed"]
     assert obs["serve"]["throughput_rps"] == rep.throughput_rps
     json.dumps(rep.to_dict())
-    # request lifecycle shows up on the session tracer
-    names = {r["name"] for r in sess.tracer.to_records()}
-    assert {"submit", "admit", "complete", "serve_step"} <= names
+    # the server's spans show up on the session tracer: one submit and
+    # one offer a call, and each step with its four parts inside
+    recs = sess.tracer.to_records()
+    names = [r["name"] for r in recs]
+    assert names.count("submit") == rep.counters["submitted"]
+    assert names.count("offer") == rep.counters["submitted"] * 3
+    assert names.count("step") >= rep.counters["steps"]
+    for part in ("admit", "upload", "fetch", "complete"):
+        assert part in names
+    depth = {r["name"]: r["depth"] for r in recs}
+    assert depth["admit"] == depth["fetch"] == depth["step"] + 1
+    assert all(r["args"] == {} for r in recs if r["cat"] == "serve")
 
 
 def test_prometheus_text_is_a_valid_exposition(served):
