@@ -82,9 +82,8 @@ def _bench_engine(fed, run_round, n_steps, iters=3):
     """run_round(params, opt_state, sched_state) must return
     (params, opt_state, sched_state, losses)."""
     def fresh():
-        ik, _ = train_keys(jax.random.PRNGKey(0))
-        p = fed.init_params(ik)
-        return p, jax.vmap(fed.opt.init)(p), fed.init_sched_state()
+        _, p, o, _ = fed._init(jax.random.PRNGKey(0))
+        return p, o, fed.init_sched_state()
 
     p, o, st = fresh()
     p, o, st, losses = run_round(p, o, st)      # warm-up / compile

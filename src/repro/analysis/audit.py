@@ -28,7 +28,6 @@ from typing import Optional, Sequence
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 
 from repro.analysis import deadness as DN
 from repro.analysis import retrace as RT
@@ -81,11 +80,10 @@ class TracedRound:
         self.round_fn = make_round_fn(fed.model, fed.opt, pcfg,
                                       self.n_train, layout=fed.layout,
                                       sched_impl=fed._impl)
-        params = fed.init_params(jax.random.PRNGKey(pcfg.seed))
-        opt_state = jax.vmap(fed.opt.init)(params)
+        _, params, opt_state, step0 = fed._init(
+            jax.random.PRNGKey(pcfg.seed))
         sched_state = fed.init_sched_state()
         self.args = (params, opt_state, sched_state, fed._xtr)
-        step0 = jnp.zeros((), jnp.int32)
         key = jax.random.fold_in(jax.random.PRNGKey(pcfg.seed), 1)
         ytr, lay = fed._ytr, fed._lay
 

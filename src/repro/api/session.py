@@ -374,11 +374,11 @@ class Session:
             return self.run(retry=retry)
         fed = self.federation
         want_sched = _hash_array(_stream_stamp(spec))
-        init_key, _ = train_keys(jax.random.PRNGKey(spec.seed))
-        params_like = fed.init_params(init_key)
+        _, params_like, opt_like, step_like = fed._init(
+            jax.random.PRNGKey(spec.seed))
         like_base = {"params": params_like,
-                     "opt_state": jax.vmap(fed.opt.init)(params_like),
-                     "step_idx": jnp.zeros((), jnp.int32),
+                     "opt_state": opt_like,
+                     "step_idx": step_like,
                      "sched": fed.init_sched_state(),
                      "resume_hash": _hash_array(spec.resume_hash)}
         state, step = None, None
@@ -579,14 +579,12 @@ class Session:
         fed = self.federation
         policy = self._retry_policy(retry)
         key = key if key is not None else jax.random.PRNGKey(spec.seed)
-        init_key, loop_key = train_keys(key)
         if state is None:
             with self.tracer.span("init", cat="setup"):
-                params = fed.init_params(init_key)
-                opt_state = jax.vmap(fed.opt.init)(params)
-                step_idx = jnp.zeros((), jnp.int32)
+                loop_key, params, opt_state, step_idx = fed._init(key)
                 sched_state = fed.init_sched_state()
         else:
+            _, loop_key = train_keys(key)
             params, opt_state, step_idx, sched_state = state
         history = []
         trips = retries = attempt = 0

@@ -872,12 +872,22 @@ class DeVertiFL:
         self._xte = jnp.asarray(self.layout.apply(xte))
         self._ytr = jnp.asarray(ytr)
         self.opt = adam(pcfg.lr, max_grad_norm=None)
+        self._init_traces = 0
         self._build_steps()
 
     # ------------------------------------------------------------------
     def init_params(self, key):
-        return init_padded_params(self.model, key, self.pcfg.n_clients,
-                                  self.pcfg.padded_clients)
+        """Initial per-client params (live and padded slots) drawn from
+        the init key ``key``.  Compiled, so they equal the ``params`` of
+        the set-up program ``_init`` bit for bit; an eager init differs
+        from a compiled one by an ulp in places."""
+        return self._init_params(key)
+
+    @property
+    def init_traces(self) -> int:
+        """Trace count of the call set-up program ``_init`` -- 1 after
+        any number of training calls on this federation."""
+        return self._init_traces
 
     # ------------------------------------------------------------------
     def _build_steps(self):
@@ -890,6 +900,22 @@ class DeVertiFL:
         self.n_batches, self.bs = plan.n_batches, plan.batch_size
         self._steps_per_round = pcfg.epochs * plan.n_batches
         self._perms = jax.jit(plan.perms)
+        init_params = partial(init_padded_params, self.model,
+                              n_clients=pcfg.n_clients,
+                              padded_clients=pcfg.padded_clients)
+
+        def setup(key):
+            # a training call's whole set-up as one dispatch: the loop
+            # key, params, Adam moments and step index, each output its
+            # own buffer for the round to consume by donation
+            self._init_traces += 1
+            init_key, loop_key = train_keys(key)
+            params = init_params(init_key)
+            return (loop_key, params, jax.vmap(self.opt.init)(params),
+                    jnp.zeros((), jnp.int32))
+
+        self._init = jax.jit(setup)
+        self._init_params = jax.jit(init_params)
         self._round = jax.jit(
             make_round_fn(self.model, self.opt, pcfg, n_train,
                           fedavg_fn=fa, layout=self.layout,
@@ -1015,10 +1041,7 @@ class DeVertiFL:
         pcfg = self.pcfg
         engine = engine or pcfg.engine
         key = key if key is not None else jax.random.PRNGKey(pcfg.seed)
-        init_key, loop_key = train_keys(key)
-        params = self.init_params(init_key)
-        opt_state = jax.vmap(self.opt.init)(params)
-        step_idx = jnp.zeros((), jnp.int32)
+        loop_key, params, opt_state, step_idx = self._init(key)
         sched_state = self.init_sched_state()
         history = []
         for r in range(pcfg.rounds):

@@ -233,6 +233,48 @@ def test_session_reproduces_legacy_bitwise(mode, fl, padded):
     assert rr.metrics == final
 
 
+@pytest.mark.parametrize("obs", ["none", "basic"])
+def test_repeated_session_runs_share_one_set_up_program(obs):
+    """Every Session.run sets up through the one compiled program: it
+    traces once, each call's set-up sits in its own ``init`` span, and
+    the round consuming its outputs by donation leaves later calls
+    bitwise equal to the first."""
+    sess = build(ExperimentSpec(seeds=(0,), obs=obs, **TINY))
+    runs = [sess.run() for _ in range(3)]
+    assert sess.federation.init_traces == 1
+    if sess.tracer.active:
+        assert [r["name"] for r in sess.tracer.records].count("init") == 3
+    for rr in runs[1:]:
+        for a, b in zip(jax.tree.leaves(runs[0].params),
+                        jax.tree.leaves(rr.params)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(
+            np.concatenate([h["round_losses"] for h in rr.history]),
+            np.concatenate([h["round_losses"]
+                            for h in runs[0].history]))
+
+
+@pytest.mark.parametrize("max_clients", [None, 6])
+def test_init_params_equals_set_up_program(max_clients):
+    """fed.init_params(init_key) is the set-up program's params bit for
+    bit, and its loop key is train_keys' (resume and the call share
+    one initialisation)."""
+    from repro.core.protocol import train_keys
+    fed = DeVertiFL(ProtocolConfig(seed=0, max_clients=max_clients,
+                                   **TINY))
+    key = jax.random.PRNGKey(3)
+    loop_key, params, opt_state, step_idx = fed._init(key)
+    init_key, want_loop = train_keys(key)
+    np.testing.assert_array_equal(np.asarray(loop_key),
+                                  np.asarray(want_loop))
+    for a, b in zip(jax.tree.leaves(fed.init_params(init_key)),
+                    jax.tree.leaves(params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for m in jax.tree.leaves(opt_state):
+        assert not np.asarray(m).any()
+    assert int(step_idx) == 0
+
+
 def test_session_python_engine_matches_legacy():
     pcfg = ProtocolConfig(engine="python", seed=1, **TINY)
     _, _, final = _legacy_traj(pcfg)

@@ -64,15 +64,22 @@ def test_layout_pad_structure():
 
 
 @pytest.mark.fast
-def test_init_padded_params_live_prefix_matches_unpadded():
+@pytest.mark.parametrize("path", ["eager", "program"])
+def test_init_padded_params_live_prefix_matches_unpadded(path):
     """Live clients' init must be the unpadded derivation exactly
-    (split(key, n)[:k] != split(key, k), so this is a real contract)."""
-    from repro.configs import get_config
-    from repro.models.mlp_model import PaperMLP
-    model = PaperMLP(get_config("paper-mlp-titanic"))
+    (split(key, n)[:k] != split(key, k), so this is a real contract),
+    called eagerly and through a federation's compiled set-up."""
     key = jax.random.PRNGKey(0)
-    plain = init_padded_params(model, key, 3)
-    padded = init_padded_params(model, key, 3, 8)
+    if path == "eager":
+        from repro.configs import get_config
+        from repro.models.mlp_model import PaperMLP
+        model = PaperMLP(get_config("paper-mlp-titanic"))
+        plain = init_padded_params(model, key, 3)
+        padded = init_padded_params(model, key, 3, 8)
+    else:
+        base = ProtocolConfig(dataset="titanic", n_clients=3, seed=0)
+        plain = DeVertiFL(base)._init(key)[1]
+        padded = DeVertiFL(base.replace(max_clients=8))._init(key)[1]
     for a, b in zip(jax.tree.leaves(plain), jax.tree.leaves(padded)):
         assert b.shape[0] == 8
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b[:3]))
