@@ -2,9 +2,10 @@
 
 A cell names a configuration and a traffic mix; each is a file of its
 own (``bench/configs/<config>.json``, ``bench/traffic/<traffic>.json``),
-and each per-layer metric is a reader of its own
-(``bench/metrics/<metric>.py``).  Adding any of them is adding a file
-and an entry: nothing here changes.
+a configuration names its model family
+(``bench/families/<family>.py``), and each per-layer metric is a reader
+of its own (``bench/metrics/<metric>.py``).  Adding any of them is
+adding a file and an entry: nothing here changes.
 """
 from __future__ import annotations
 
@@ -14,6 +15,7 @@ from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = Path(__file__).resolve().parent
+FAMILIES = BENCH / "families"
 
 
 def benchmark(root: Path = ROOT) -> dict:
@@ -44,11 +46,25 @@ def cell(name: str, root: Path = ROOT) -> dict:
             "per_layer": [m for m in spec["per_layer"] if here(m)]}
 
 
-def reader(metric: str):
-    """The ``read(ctx)`` function of ``bench/metrics/<metric>.py``."""
-    path = BENCH / "metrics" / f"{metric}.py"
-    mod_spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_')}", path)
+def load(path: Path, module: str):
+    """The module kept in the file ``path``, as ``module``."""
+    mod_spec = importlib.util.spec_from_file_location(module, path)
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(metric: str):
+    """The ``read(ctx)`` function of ``bench/metrics/<metric>.py``."""
+    return load(BENCH / "metrics" / f"{metric}.py",
+                f"bench_metric_{metric.replace('.', '_')}").read
+
+
+def family(name: str):
+    """The model family module ``<FAMILIES>/<name>.py``: ``rows``,
+    ``session``, ``follow``, ``context`` and, for serving cells,
+    ``serve_params`` and ``serve_logits`` (``bench/README.md``)."""
+    path = FAMILIES / f"{name}.py"
+    if not path.is_file():
+        raise SystemExit(f"no model family {name!r}: {path} does not exist")
+    return load(path, f"bench_family_{name.replace('.', '_')}")

@@ -1,7 +1,8 @@
-"""Host wall time of each call's eager initialisation (parameters,
-optimizer state, schedule state): the program's ``devertifl.init``
-spans in the traced window, per ``Session.run`` call
-(``devertifl.run`` spans there) (profiler trace: bench.scopes)."""
+"""Host wall time of each call's set-up: one dispatch of the compiled
+set-up program (loop key, parameters, optimizer state, step index) and
+the schedule state, the program's ``devertifl.init`` spans in the
+traced window, per ``Session.run`` call (``devertifl.run`` spans
+there) (profiler trace: bench.scopes)."""
 from bench import scopes, trace
 
 

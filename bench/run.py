@@ -74,16 +74,10 @@ class CompileCounter:
 
 
 def run_context(job, cell, peak):
-    """What the metric readers read about this run."""
+    """What the metric readers read about this run: what the model
+    family says of its model (``context``), and this run's numbers."""
     cfg = cell["config"]
-    m = cfg["model"]
-    run = {"hidden": m["hidden"], "n_hidden": m["n_hidden"],
-           "n_classes": m["n_classes"]}
-    from bench import reference
-    parts = reference.partition(cfg["federation"]["partition"],
-                                m["in_features"],
-                                cfg["federation"]["n_clients"])
-    run["widths"] = [len(p) for p in parts]
+    run = dict(job.family.context(cfg))
     run["test_rows"] = int(cfg["dataset"]["rows"]
                            * cfg["dataset"]["test_frac"])
     run["batch"] = cfg["training"]["batch_size"]

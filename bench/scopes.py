@@ -147,18 +147,19 @@ def rounds(tr: trace.Trace, program: str = ROUND) -> float:
 
 def round_stacks(ctx) -> Dict[str, str]:
     """Instruction name -> name stack of the configuration's round
-    program, compiled as the training cell compiles it (the cell's
-    Session through ``bench.program``; neither the shapes nor the
-    partitions of the configurations depend on the seed).  Read once a
-    run: kept in ``ctx``, which the run hands to every reader."""
+    program, compiled as the training cell compiles it (a Session of
+    the dataset the cell registered, through ``bench.program``; neither
+    the shapes nor the partitions of the configurations depend on the
+    seed).  Read once a run: kept in ``ctx``, which the run hands to
+    every reader."""
     cfg, trf = ctx["config"], ctx["traffic"]
     if "round_stacks" not in ctx:
         import jax
         import jax.numpy as jnp
 
         from bench import program
-        sess = program.session(cfg, 0, rounds=trf["rounds_per_call"],
-                               eval_every=trf["eval_every"])
+        sess = program.build(cfg, 0, rounds=trf["rounds_per_call"],
+                             eval_every=trf["eval_every"])
         fed = sess.federation
         key = jax.random.PRNGKey(0)
         params = jax.eval_shape(fed.init_params, key)

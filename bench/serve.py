@@ -15,28 +15,11 @@ as latency.
 from __future__ import annotations
 
 import time
-from functools import partial
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from bench import check, program, reference
-
-
-@partial(jax.jit, static_argnames=("n_clients", "layer_dims"))
-def serve_params(key, *, n_clients, layer_dims):
-    """Stacked per-client towers drawn from the seed: He-normal kernels,
-    normal(0, 0.1) biases."""
-    def one(k):
-        ks = jax.random.split(k, 2 * (len(layer_dims) - 1))
-        return {f"layer_{i}": {
-            "kernel": jax.random.normal(ks[2 * i], layer_dims[i:i + 2])
-            * (2.0 / layer_dims[i]) ** 0.5,
-            "bias": 0.1 * jax.random.normal(ks[2 * i + 1],
-                                            (layer_dims[i + 1],))}
-            for i in range(len(layer_dims) - 1)}
-    return jax.vmap(one)(jax.random.split(key, n_clients))
+from bench import catalog, check, data
 
 
 class ServeCell:
@@ -44,29 +27,33 @@ class ServeCell:
         self.config, self.traffic = cell["config"], cell["traffic"]
         self.seed = seed
         self.rng = np.random.default_rng(seed)
+        name = self.config["model"]["family"]
+        self.family = catalog.family(name)
+        missing = [f for f in ("serve_params", "serve_logits")
+                   if not hasattr(self.family, f)]
+        if missing:
+            raise SystemExit(f"model family {name!r} has no "
+                             f"{' or '.join(missing)}: it cannot serve")
 
     # ------------------------------------------------------------------
     def setup(self):
         from repro.api import split_features
-        cfg, trf, m = self.config, self.traffic, self.config["model"]
+        cfg, trf = self.config, self.traffic
         self.arrays = jax.block_until_ready(
-            program.register_data(cfg, self.seed))
+            self.family.rows(cfg, self.seed))
         self.marks = [("data", time.perf_counter())]
-        sess = program.session(cfg, self.seed, rounds=1, eval_every=0)
+        sess = self.family.session(cfg, self.arrays, self.seed, rounds=1,
+                                   eval_every=0)
         fed = sess.federation
         self.marks.append(("session", time.perf_counter()))
-        self.layer_dims = tuple(reference.dims(
-            m["in_features"], m["hidden"], m["n_hidden"], m["n_classes"]))
-        self.params = serve_params(
-            jax.random.fold_in(jax.random.PRNGKey(self.seed), 0x5E7E),
-            n_clients=cfg["federation"]["n_clients"],
-            layer_dims=self.layer_dims)
+        self.params = self.family.serve_params(
+            cfg, jax.random.fold_in(jax.random.PRNGKey(self.seed), 0x5E7E))
         self.srv = sess.server(params=self.params,
                                max_slots=trf["max_slots"],
                                cache=trf["cache"])
         self.spec_hash = sess.spec.spec_hash
-        self.xte = np.asarray(self.arrays[2])
-        self.n_rows = len(self.xte)
+        self.xte = jax.tree.map(np.asarray, self.arrays[2])
+        self.n_rows = data.count(self.xte)
         self.n_live = cfg["federation"]["n_clients"]
         self.slices = split_features(fed.layout, self.xte)
         ranks = np.arange(1, self.n_rows + 1, dtype=np.float64)
@@ -179,10 +166,7 @@ class ServeCell:
         """The compared number of this run.  With ``mm`` the reference
         at that product stands in for the program (the control)."""
         cfg, trf, srv = self.config, self.traffic, self.srv
-        m, fed = cfg["model"], cfg["federation"]
-        parts = reference.partition(fed["partition"], m["in_features"],
-                                    fed["n_clients"])
-        order, slices = reference.canonical(parts)
+        logits = self.family.serve_logits
         rng = np.random.default_rng([self.seed, 0xC4EC])
         window = [u for u in self.window_uids.tolist() if u in srv.results]
         pick = np.sort(rng.choice(len(window),
@@ -196,25 +180,21 @@ class ServeCell:
         held = np.sort(rng.choice(held, min(trf["check_cached"], len(held)),
                                   replace=False)) if held else \
             np.zeros(0, int)
-        x = jnp.asarray(self.xte[:, order])
-        _, ref_sum = reference.serve_logits(self.params, x[rows],
-                                            slices=slices)
-        ref_held, _ = reference.serve_logits(self.params, x[held],
-                                             slices=slices)
+        x_rows, x_held = data.take(self.xte, rows), data.take(self.xte, held)
+        _, ref_sum = logits(cfg, self.params, x_rows)
+        ref_held = np.asarray(logits(cfg, self.params, x_held)[0]
+                              ).transpose(1, 0, 2)
         if mm is None:          # what the program served and cached
             preds = np.stack([srv.results[u] for u in uids]) if uids \
                 else np.zeros((0, self.n_live), int)
             cached = np.stack([np.asarray(srv.cache.lookup(
                 (self.spec_hash, int(e)))) for e in held]) if len(held) \
-                else np.zeros((0, self.n_live, m["n_classes"]))
+                else np.zeros(ref_held.shape)
         else:                   # the control in the program's place
-            _, ctl_sum = reference.serve_logits(self.params, x[rows],
-                                                slices=slices, mm=mm)
+            _, ctl_sum = logits(cfg, self.params, x_rows, mm=mm)
             preds = np.repeat(np.asarray(ctl_sum).argmax(1)[:, None],
                               self.n_live, axis=1)
-            cached = np.asarray(reference.serve_logits(
-                self.params, x[held], slices=slices, mm=mm)[0]
-            ).transpose(1, 0, 2)
-        gap = check.logit_gap(preds, ref_sum, cached,
-                              np.asarray(ref_held).transpose(1, 0, 2))
-        return {"logit_gap": gap}
+            cached = np.asarray(logits(cfg, self.params, x_held, mm=mm)[0]
+                                ).transpose(1, 0, 2)
+        return {"logit_gap": check.logit_gap(preds, ref_sum, cached,
+                                             ref_held)}

@@ -15,24 +15,25 @@ import time
 import jax
 import numpy as np
 
-from bench import check, program, reference
+from bench import catalog, check
 
 
 class TrainCell:
     def __init__(self, cell: dict, seed: int):
         self.config, self.traffic = cell["config"], cell["traffic"]
         self.seed = seed
+        self.family = catalog.family(self.config["model"]["family"])
 
     # ------------------------------------------------------------------
     def setup(self):
         """Build the Session and drive its first call from the seed."""
         cfg, trf = self.config, self.traffic
         self.arrays = jax.block_until_ready(
-            program.register_data(cfg, self.seed))
+            self.family.rows(cfg, self.seed))
         self.marks = [("data", time.perf_counter())]
-        self.sess = program.session(cfg, self.seed,
-                                    rounds=trf["rounds_per_call"],
-                                    eval_every=trf["eval_every"])
+        self.sess = self.family.session(cfg, self.arrays, self.seed,
+                                        rounds=trf["rounds_per_call"],
+                                        eval_every=trf["eval_every"])
         fed = self.sess.federation
         self.marks.append(("session", time.perf_counter()))
         self.rounds = trf["rounds_per_call"]
@@ -85,23 +86,12 @@ class TrainCell:
         self.sess = None
 
     # ------------------------------------------------------------------
-    def follow(self, rounds, mm=reference.matmul_highest, device=None):
-        """The reference (or, with a lower ``mm``, the control) from the
-        seed through ``rounds`` rounds of this cell's training rows."""
-        cfg = self.config
-        m, fed, trn = cfg["model"], cfg["federation"], cfg["training"]
-        parts = reference.partition(fed["partition"], m["in_features"],
-                                    fed["n_clients"])
-        order, slices = reference.canonical(parts)
-        x, y = self.arrays[0][:, order], jax.numpy.asarray(self.arrays[1])
-        with jax.default_device(device):
-            x, y = jax.device_put((x, y), device)
-            return reference.train(
-                self.seed, x, y, slices,
-                reference.dims(m["in_features"], m["hidden"],
-                               m["n_hidden"], m["n_classes"]),
-                rounds=rounds, epochs=trn["epochs"],
-                batch=trn["batch_size"], lr=trn["lr"], mm=mm)
+    def follow(self, rounds, mm=None, device=None):
+        """The reference (or, with a lower product ``mm``, the control)
+        from the seed through ``rounds`` rounds of this cell's training
+        rows (the model family's ``follow``)."""
+        return self.family.follow(self.config, self.arrays, self.seed,
+                                  rounds, mm=mm, device=device)
 
     def numbers(self, mm=None):
         """The compared numbers of the set-up call against the

@@ -7,8 +7,7 @@ import pytest
 from benchcells import small_cell
 
 import jax  # noqa: E402
-from bench import reference  # noqa: E402
-from bench.serve import serve_params  # noqa: E402
+from bench import catalog, reference  # noqa: E402
 from bench.train import TrainCell  # noqa: E402
 
 
@@ -28,16 +27,12 @@ def test_reference_follows_session_run(name):
 
 
 def test_reference_forward_matches_predict():
-    from bench import program
+    mlp = catalog.family("mlp")
     cell = small_cell("mnist5.serve")
     cfg = cell["config"]
-    arrays = program.register_data(cfg, 3)
-    sess = program.session(cfg, 3, rounds=1, eval_every=0)
-    m = cfg["model"]
-    dims = tuple(reference.dims(m["in_features"], m["hidden"],
-                                m["n_hidden"], m["n_classes"]))
-    params = serve_params(jax.random.PRNGKey(3), n_clients=5,
-                          layer_dims=dims)
+    arrays = mlp.rows(cfg, 3)
+    sess = mlp.session(cfg, arrays, 3, rounds=1, eval_every=0)
+    params = mlp.serve_params(cfg, jax.random.PRNGKey(3))
     xte = np.asarray(arrays[2])
     got = np.asarray(sess.predict(xte, params=params))      # [n, B]
     order, slices = reference.canonical(reference.partition(

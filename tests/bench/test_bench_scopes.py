@@ -8,7 +8,7 @@ import pytest
 
 from benchcells import small_cell
 
-from bench import catalog, program, scopes, trace  # noqa: E402
+from bench import catalog, scopes, trace  # noqa: E402
 from bench.trace import Event  # noqa: E402
 
 
@@ -257,7 +257,9 @@ def test_stacks_map_compiled_instructions_to_their_scopes():
 def test_round_stacks_reads_the_cells_round_program():
     cell = small_cell("mnist5.train")
     cfg = cell["config"]
-    program.register_data(cfg, 2**31 + 31)
+    mlp = catalog.family(cfg["model"]["family"])
+    mlp.session(cfg, mlp.rows(cfg, 2**31 + 31), 2**31 + 31, rounds=1,
+                eval_every=0)
     names = scopes.round_stacks({"config": cfg,
                                  "traffic": cell["traffic"]})
     for scope in ("batch", "first_layer", "tower", "exchange", "loss",
